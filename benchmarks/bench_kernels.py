@@ -87,8 +87,8 @@ def tree_attention_paged_sweep(*, B=2, Hq=4, Hkv=2, D=64, T=16,
     for bs in (16, 128):
         M = max_len // bs
         num_blocks = 1 + B * M                     # dense-equivalent pool
-        pool_k, pool_v = r(3, (num_blocks, bs, Hkv, D)), r(
-            4, (num_blocks, bs, Hkv, D))
+        pool_k, pool_v = r(3, (num_blocks, Hkv, bs, D)), r(
+            4, (num_blocks, Hkv, bs, D))
         for occupancy in (0.25, 0.5, 1.0):
             lens = np.full(B, int(occupancy * max_len) - T, np.int64)
             lens = np.maximum(lens, 1)
@@ -104,8 +104,8 @@ def tree_attention_paged_sweep(*, B=2, Hq=4, Hkv=2, D=64, T=16,
 
             # the three data paths (kernels in interpret mode for max-err,
             # jnp refs for CPU wall-clock proxies)
-            gather = jax.jit(lambda pk, t: pk[t].reshape(
-                B, M * bs, Hkv, D).transpose(0, 2, 1, 3))
+            gather = jax.jit(lambda pk, t: pk[t].transpose(
+                0, 2, 1, 3, 4).reshape(B, Hkv, M * bs, D))
             ck, cv = gather(pool_k, table_j), gather(pool_v, table_j)
             o_dense = tree_attention(q, ck, cv, tk, tv, tm, lens_j,
                                      bk=bs, interpret=True)
@@ -187,8 +187,8 @@ def paged_decode_variants(*, B=2, Hq=4, Hkv=2, D=64, T=16,
 
         # sliding-window group
         q = r(0, (B, T, Hq, D))
-        pk, pv = r(1, (num_blocks, bs, Hkv, D)), r(2, (num_blocks, bs,
-                                                       Hkv, D))
+        pk, pv = r(1, (num_blocks, Hkv, bs, D)), r(2, (num_blocks, Hkv,
+                                                       bs, D))
         tk, tv = r(3, (B, T, Hkv, D)), r(4, (B, T, Hkv, D))
         w = jnp.int32(window)
         kernel = lambda a: tree_attention_paged_windowed_bshd(

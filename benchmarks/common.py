@@ -20,13 +20,6 @@ import os
 import time
 from functools import lru_cache
 
-# The serving benchmarks measure host/device overlap, which the legacy
-# CPU runtime's serialized pipelined dispatch would invert — opt into the
-# thunk runtime before the backend initializes (see runtime_env).
-from repro.runtime_env import enable_cpu_thunk_runtime
-
-enable_cpu_thunk_runtime()
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -37,8 +30,12 @@ from repro.core.heads import init_draft_params
 from repro.core.trees import TreeSpec, default_tree
 from repro.data.synthetic import DataPipeline, MarkovSpec
 from repro.models.model import init_params
+from repro.runtime_env import use_compilation_cache
 from repro.training.checkpoint import load_checkpoint, save_checkpoint
 from repro.training.trainer import TrainConfig, train_base, train_heads
+
+# every benchmark process shares the persistent compile cache
+use_compilation_cache()
 
 CKPT_DIR = os.path.join(os.path.dirname(__file__), "..", "results", "ckpt")
 FAST = os.environ.get("REPRO_BENCH_FAST", "1") == "1"

@@ -19,6 +19,7 @@ heads read its output instead of the raw base hidden state.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -35,7 +36,10 @@ from repro.models.layers import dense_init, init_mlp, mlp_fwd, rms_norm
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.jit, static_argnames="cfg")
 def init_draft_params(key, cfg: ModelConfig):
+    """Random draft-head parameters in ``cfg.dtype``, built under jit
+    like ``models.model.init_params``."""
     dc = cfg.draft
     d, V = cfg.d_model, cfg.vocab_size
     dtype = jnp.dtype(cfg.dtype)

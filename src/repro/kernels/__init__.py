@@ -1,13 +1,9 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-"""Version-compat shims + backend resolution shared by the Pallas kernels.
+"""Backend resolution shared by the Pallas kernels.
 
-jax renamed ``pltpu.TPUCompilerParams`` -> ``pltpu.CompilerParams`` around
-0.5.x; the installed toolchain may carry either name.  Kernels import
-``tpu_compiler_params`` from here instead of touching ``pltpu`` directly.
-
-This module is ALSO the single backend-resolution path for every kernel
+This module is the single backend-resolution path for every kernel
 entry point (DESIGN.md §11):
 
 * ``resolve_backend()``  — the jax platform name, resolved once per
@@ -36,20 +32,10 @@ import os
 from functools import lru_cache
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
 
 AUTOTUNE_ENV = "REPRO_AUTOTUNE"
 
 _log = logging.getLogger("repro.kernels")
-
-
-def tpu_compiler_params(*, dimension_semantics, **kwargs):
-    """Construct TPU compiler params under either pltpu API name."""
-    return _COMPILER_PARAMS_CLS(dimension_semantics=dimension_semantics,
-                                **kwargs)
 
 
 @lru_cache(maxsize=1)

@@ -14,9 +14,11 @@ Orthogonal axes of the spec:
 
 * ``layout`` — the cache adapter.  ``"dense"`` walks per-slot
   ``(B, Hkv, S, D)`` strips in ``bk``-sized tiles; ``"paged"`` walks a
-  global pool ``(num_blocks, block_size, Hkv, D)`` through a
+  head-major global pool ``(num_blocks, Hkv, block_size, D)`` through a
   scalar-prefetched ``block_table[b, j]`` (NULL entries and entries past
-  ``cache_len`` are compute-skipped — ragged early-exit).
+  ``cache_len`` are compute-skipped — ragged early-exit).  Both layouts
+  put the token axis second-to-last, so every cache tile is a
+  ``(bk, D)`` slab that the TPU's (sublane, lane) tiling accepts.
 * ``windowed`` — the sliding-window mask-mod hook: a TRACED window (one
   int32, scalar-prefetched, so one compiled kernel serves a scan group
   mixing local and global layers) plus absolute query positions
@@ -53,7 +55,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import resolve_interpret, tpu_compiler_params
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 NULL_BLOCK = 0   # physical pool block 0 is reserved; never read unmasked
@@ -231,7 +233,7 @@ def self_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -275,7 +277,7 @@ def _tree_template_body(spec: TemplateSpec, *refs, bk: int, scale: float,
 
     if spec.windowed:
         w = win_ref[0]
-        q_abs = qpos_ref[0]                                  # (T,) int32
+        q_abs = qpos_ref[0, 0]                               # (T,) int32
 
     in_cache = jnp.logical_and(j < n_steps, j * bk < cache_len)
     if paged:
@@ -285,13 +287,14 @@ def _tree_template_body(spec: TemplateSpec, *refs, bk: int, scale: float,
         # Every real query row has q_pos >= cache_len (verify positions
         # are cache_len + depth), so a cache block whose last position
         # sits at or behind cache_len - w is invisible to ALL rows.
+        # (written without a boolean select: Mosaic cannot lower one)
         reachable = (j + 1) * bk - 1 > cache_len - w
-        in_cache = jnp.logical_and(in_cache, jnp.where(w > 0, reachable,
-                                                       True))
+        in_cache = jnp.logical_and(in_cache,
+                                   jnp.logical_or(w <= 0, reachable))
 
     def _load(ref):
-        # dense strips are (1, 1, bk, D) tiles; pool blocks (1, bk, 1, D)
-        return (ref[0, :, 0] if paged else ref[0, 0]).astype(jnp.float32)
+        # dense strips and pool blocks are both (1, 1, bk, D) tiles
+        return ref[0, 0].astype(jnp.float32)
 
     @pl.when(in_cache)
     def _cache_step():
@@ -306,7 +309,7 @@ def _tree_template_body(spec: TemplateSpec, *refs, bk: int, scale: float,
         mask = k_pos < cache_len
         if spec.windowed:
             mask = jnp.logical_and(
-                mask, jnp.where(w > 0, q_abs[:, None] - k_pos < w, True))
+                mask, jnp.logical_or(w <= 0, q_abs[:, None] - k_pos < w))
         _softmax_update(q, k, v, mask, m_sc, l_sc, acc_sc)
 
     @pl.when(j == n_steps)
@@ -325,7 +328,7 @@ def _tree_template_body(spec: TemplateSpec, *refs, bk: int, scale: float,
             kv_pos = cache_len + jax.lax.broadcasted_iota(
                 jnp.int32, (T, T), 1)
             mask = jnp.logical_and(
-                mask, jnp.where(w > 0, q_abs[:, None] - kv_pos < w, True))
+                mask, jnp.logical_or(w <= 0, q_abs[:, None] - kv_pos < w))
         _softmax_update(q, k, v, mask, m_sc, l_sc, acc_sc)
         o_ref[0, 0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
                        ).astype(o_ref.dtype)
@@ -343,9 +346,10 @@ def tree_attention_template(q, cache_k, cache_v, tree_k, tree_v, tree_mask,
     """Template instantiation, tree family (kernel layout).
 
     q: (B,Hq,T,Dk).  Non-MLA: cache_k/v are the dense per-slot cache
-    (B,Hkv,S,D) or the global pool (num_blocks, block_size, Hkv, D);
-    tree_k/v: (B,Hkv,T,D).  MLA (``spec.mla``): cache_k/cache_k2 carry
-    the latent (rank r) and RoPE (rank rd) streams with Hkv == 1,
+    (B,Hkv,S,D) or the head-major global pool (num_blocks, Hkv,
+    block_size, D); tree_k/v: (B,Hkv,T,D).  MLA (``spec.mla``):
+    cache_k/cache_k2 carry the latent (rank r) and RoPE (rank rd)
+    streams with Hkv == 1,
     ``cache_v``/``tree_v`` must be None, and the result is o_lat
     (B,Hq,T,r).  Paged (``spec.layout == 'paged'``): ``block_table``
     (B, M) int32 required; the kv tile IS the allocator's block_size.
@@ -370,7 +374,7 @@ def tree_attention_template(q, cache_k, cache_v, tree_k, tree_v, tree_mask,
     else:
         dims = (Dk,)
         Dv = Dk
-    Hkv = cache_k.shape[2] if paged else cache_k.shape[1]
+    Hkv = cache_k.shape[1]
     G = Hq // Hkv
     if scale is None:
         scale = 1.0 / (Dk ** 0.5)
@@ -379,7 +383,7 @@ def tree_attention_template(q, cache_k, cache_v, tree_k, tree_v, tree_mask,
     if paged:
         if block_table is None:
             raise ValueError("paged template requires a block_table")
-        bs = cache_k.shape[1]
+        bs = cache_k.shape[2]
         if bs % 8 != 0:
             # the allocator's block_size IS the kv tile's sublane extent:
             # 8 is the f32 tiling floor
@@ -418,12 +422,11 @@ def tree_attention_template(q, cache_k, cache_v, tree_k, tree_v, tree_mask,
                              lambda b, h, j, *pf: (b, h, 0, 0))]
     if paged:
         def kv_map(b, h, j, *pf):
-            return (pf[1][b, clamp(j)], 0, h // G, 0)
-        kv_block = lambda d: (1, bk, 1, d)
+            return (pf[1][b, clamp(j)], h // G, 0, 0)
     else:
         def kv_map(b, h, j, *pf):
             return (b, h // G, clamp(j), 0)
-        kv_block = lambda d: (1, 1, bk, d)
+    kv_block = lambda d: (1, 1, bk, d)
     tree_map = lambda b, h, j, *pf: (b, h // G, 0, 0)
 
     cache_streams = ((cache_k, cache_k2) if spec.mla
@@ -438,8 +441,10 @@ def tree_attention_template(q, cache_k, cache_v, tree_k, tree_v, tree_mask,
     operands.append(tree_mask)
     in_specs.append(pl.BlockSpec((T, T), lambda b, h, j, *pf: (0, 0)))
     if spec.windowed:
-        operands.append(q_pos.astype(jnp.int32))
-        in_specs.append(pl.BlockSpec((1, T), lambda b, h, j, *pf: (b, 0)))
+        # (B, 1, T): a (1, 1, T) block's last two dims equal the array's
+        operands.append(q_pos.astype(jnp.int32)[:, None, :])
+        in_specs.append(pl.BlockSpec((1, 1, T),
+                                     lambda b, h, j, *pf: (b, 0, 0)))
 
     body = functools.partial(_tree_template_body, spec, bk=bk, scale=scale,
                              n_steps=n_steps, T=T)
@@ -459,7 +464,7 @@ def tree_attention_template(q, cache_k, cache_v, tree_k, tree_v, tree_mask,
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, T, Dv), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*prefetch, *operands)

@@ -41,7 +41,8 @@ def tree_attention_paged_windowed_bshd(q, pool_k, pool_v, tree_k, tree_v,
                                        interpret: bool | None = None):
     """Sliding-window tree verification streaming K/V from the block pool.
 
-    Same contract as ``tree_attention_paged_bshd`` plus ``q_pos`` (B, T)
+    Same contract as ``tree_attention_paged_bshd`` (pools head-major,
+    ``(num_blocks, Hkv, block_size, D)``) plus ``q_pos`` (B, T)
     int32 absolute query positions and ``window`` (traced int32 scalar;
     <= 0 means full attention, so one compiled kernel serves a scan
     group mixing local and global layers).  Precondition: every real
@@ -49,7 +50,7 @@ def tree_attention_paged_windowed_bshd(q, pool_k, pool_v, tree_k, tree_v,
     ``cache_len + depth``).  Returns (B, T, Hq, D).
     """
     D = q.shape[-1]
-    bs = pool_k.shape[1]
+    bs = pool_k.shape[2]
     if pad_to is None:
         pad_to = tuned_block_sizes("tree_paged_windowed", D, block_size=bs,
                                    defaults={"pad_to": 8})["pad_to"]
@@ -77,7 +78,8 @@ def mla_attention_paged_bshd(q_lat, q_rope, pool_lat, pool_rope, tree_lat,
     K tiles are ``[latent ‖ rope]`` concatenated in-register; V is the
     latent stream, so the result is ``o_lat`` which the caller un-absorbs
     through ``w_uv``.  q_lat: (B,T,H,r) = q_nope @ w_uk (absorbed);
-    q_rope: (B,T,H,rd); pool_lat: (N,bs,r); pool_rope: (N,bs,rd);
+    q_rope: (B,T,H,rd); pool_lat: (N,bs,r); pool_rope: (N,bs,rd) — one
+    latent head, so the kernel sees them as head-major (N,1,bs,*);
     tree_lat: (B,T,r); tree_rope: (B,T,rd).  ``scale`` is the absorbed
     score scale 1/sqrt(nd+rd) — NOT derivable from the latent ranks.
     Pass ``q_pos``/``window`` together to window the scores (unused by
@@ -101,10 +103,10 @@ def mla_attention_paged_bshd(q_lat, q_rope, pool_lat, pool_rope, tree_lat,
         q_pos = _pad_axis1(q_pos, Tp)
     tr = lambda t: t.transpose(0, 2, 1, 3)
     o = tree_attention_template(
-        tr(q), pool_lat[:, :, None, :], None,
+        tr(q), pool_lat[:, None], None,
         tr(tree_lat[:, :, None, :]), None, tm, cache_len, block_table,
         window if windowed else None, q_pos if windowed else None,
-        cache_k2=pool_rope[:, :, None, :],
+        cache_k2=pool_rope[:, None],
         tree_k2=tr(tree_rope[:, :, None, :]),
         spec=TemplateSpec(kind="tree", layout="paged", mla=True,
                           windowed=windowed),

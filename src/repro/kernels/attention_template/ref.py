@@ -7,20 +7,22 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.tree_attention.ref import gather_pool_heads
+
 
 def tree_attention_paged_windowed_ref(q, pool_k, pool_v, tree_k, tree_v,
                                       tree_mask, cache_len, block_table,
                                       q_pos, window):
-    """Kernel-layout oracle.  q: (B,Hq,T,D); pool_k/v: (N,bs,Hkv,D);
+    """Kernel-layout oracle.  q: (B,Hq,T,D); pool_k/v: (N,Hkv,bs,D);
     tree_k/v: (B,Hkv,T,D); q_pos: (B,T); window: int32 scalar (<=0 off).
     Tree token j sits at absolute position ``cache_len + j``."""
     B, Hq, T, D = q.shape
-    bs, Hkv = pool_k.shape[1], pool_k.shape[2]
+    Hkv, bs = pool_k.shape[1], pool_k.shape[2]
     M = block_table.shape[1]
     S = M * bs
     G = Hq // Hkv
-    ck = pool_k[block_table].reshape(B, S, Hkv, D).transpose(0, 2, 1, 3)
-    cv = pool_v[block_table].reshape(B, S, Hkv, D).transpose(0, 2, 1, 3)
+    ck = gather_pool_heads(pool_k, block_table)            # (B,Hkv,S,D)
+    cv = gather_pool_heads(pool_v, block_table)
     covered = jnp.repeat(block_table != 0, bs, axis=1)            # (B,S)
 
     kx = jnp.repeat(jnp.concatenate([ck, tree_k], axis=2), G, axis=1)
@@ -39,9 +41,7 @@ def tree_attention_paged_windowed_ref(q, pool_k, pool_v, tree_k, tree_v,
     abs_kv = jnp.where(kv_pos[None] < S, kv_pos[None],
                        cache_len[:, None] + (kv_pos[None] - S))   # (B,S+T)
     w = jnp.asarray(window)
-    win_ok = jnp.where(w > 0,
-                       q_pos[:, :, None] - abs_kv[:, None, :] < w, True)
-    mask = mask & win_ok
+    mask = mask & ((w <= 0) | (q_pos[:, :, None] - abs_kv[:, None, :] < w))
 
     s = jnp.where(mask[:, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
@@ -83,8 +83,8 @@ def mla_attention_paged_ref(q_lat, q_rope, pool_lat, pool_rope, tree_lat,
         abs_kv = jnp.where(kv_pos[None] < S, kv_pos[None],
                            cache_len[:, None] + (kv_pos[None] - S))
         w = jnp.asarray(window)
-        mask = mask & jnp.where(
-            w > 0, q_pos[:, :, None] - abs_kv[:, None, :] < w, True)
+        mask = mask & ((w <= 0)
+                       | (q_pos[:, :, None] - abs_kv[:, None, :] < w))
 
     s = jnp.where(mask[:, :, None, :], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)

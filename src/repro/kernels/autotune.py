@@ -118,8 +118,8 @@ def _bench_fn(variant: str, head_dim: int, block_size: int | None,
     N = 2 * M + 2
     table = _cover_tables([int(x) for x in lens], _T, bs, M, N)
     if variant in ("tree_paged", "tree_paged_windowed"):
-        pk = _rand(key, 1, (N, bs, _HKV, D))
-        pv = _rand(key, 2, (N, bs, _HKV, D))
+        pk = _rand(key, 1, (N, _HKV, bs, D))
+        pv = _rand(key, 2, (N, _HKV, bs, D))
         q = _rand(key, 0, (_B, _T, _HQ, D))
         tk = _rand(key, 3, (_B, _T, _HKV, D))
         tv = _rand(key, 4, (_B, _T, _HKV, D))

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import resolve_interpret, tpu_compiler_params
+from repro.kernels import resolve_interpret
 
 LOG_DECAY_CLAMP = -20.0
 
@@ -100,7 +100,7 @@ def linear_attn_chunk(r, k, v, w_log, u=None, *, chunk: int = 64,
         out_specs=pl.BlockSpec((1, 1, chunk, dv), lambda b, h, j: (b, h, j, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, dv), v.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w_log, u)

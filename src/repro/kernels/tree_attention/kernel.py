@@ -19,8 +19,8 @@ Two cache layouts share the same sweep:
   grid's cache axis walks S in ``bk``-sized strips.  ``bk=None`` takes
   the autotuned winner (key ``tree_dense|hd=<D>``); sizes that don't
   tile S are legalized by pad-or-clamp instead of asserting.
-* ``tree_attention_paged`` — vLLM-style global block pool
-  ``(num_blocks, block_size, Hkv, D)`` plus a per-slot block table
+* ``tree_attention_paged`` — vLLM-style global block pool, head-major
+  ``(num_blocks, Hkv, block_size, D)``, plus a per-slot block table
   ``(B, M)``; the grid's cache axis walks *table entries*, each index map
   scalar-prefetches ``block_table[b, j]`` so K/V blocks stream straight
   from the pool with no dense intermediate.  NULL-table entries (physical
@@ -28,9 +28,8 @@ Two cache layouts share the same sweep:
   ragged early-exit for short slots; runs of skipped entries all map to
   block 0, so Mosaic's revisit elision drops their copies after the first.
   The cache tile here is the ALLOCATOR's ``block_size`` (sublane axis:
-  must be a multiple of 8 — ValueError otherwise; compiled TPU runs want
-  128+ for full MXU tiles — the engine's CPU-test default of 16 is
-  interpret-mode fare).
+  must be a multiple of 8 — ValueError otherwise; bf16 pools want a
+  multiple of 16, and larger blocks fill more of the MXU).
 
 Grid: (B, Hq, n_cache_blocks + 1), innermost 'arbitrary' (sequential).
 """
@@ -65,10 +64,10 @@ def tree_attention_paged(q, pool_k, pool_v, tree_k, tree_v, tree_mask,
                          interpret: bool | None = None):
     """Tree verification streaming K/V from a paged block pool.
 
-    q: (B,Hq,T,D); pool_k/v: (num_blocks, block_size, Hkv, D) — the global
-    pool, NOT a per-slot view; tree_k/v: (B,Hkv,T,D); tree_mask: (T,T)
-    bool ancestor-or-self; cache_len: (B,) int32 committed length per
-    slot; block_table: (B, M) int32 physical block ids (0 = NULL).
+    q: (B,Hq,T,D); pool_k/v: (num_blocks, Hkv, block_size, D) — the
+    global head-major pool, NOT a per-slot view; tree_k/v: (B,Hkv,T,D);
+    tree_mask: (T,T) bool ancestor-or-self; cache_len: (B,) int32
+    committed length per slot; block_table: (B, M) int32 physical block ids (0 = NULL).
     interpret: None => auto (compile on TPU, interpret elsewhere).
     Returns (B,Hq,T,D).
 

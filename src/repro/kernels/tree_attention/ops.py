@@ -60,12 +60,13 @@ def tree_attention_paged_bshd(q, pool_k, pool_v, tree_k, tree_v, tree_mask,
                               cache_len, block_table, *,
                               pad_to: int | None = None,
                               interpret: bool | None = None):
-    """q/tree k,v: (B,T,H*,D) model layout; pool_k/v: the global pool
-    (num_blocks, block_size, Hkv, D) — streamed in place, never gathered;
+    """q/tree k,v: (B,T,H*,D) model layout; pool_k/v: the head-major
+    global pool (num_blocks, Hkv, block_size, D) — streamed in place,
+    never gathered;
     block_table: (B, M) int32.  Returns (B,T,Hq,D)."""
     if pad_to is None:
         pad_to = tuned_block_sizes("tree_paged", q.shape[-1],
-                                   block_size=pool_k.shape[1],
+                                   block_size=pool_k.shape[2],
                                    defaults=_PAD_DEFAULTS)["pad_to"]
     q, tree_k, tree_v, tree_mask, T = _pad_tree(q, tree_k, tree_v,
                                                 tree_mask, pad_to)

@@ -12,16 +12,21 @@ def tree_attention_paged_ref(q, pool_k, pool_v, tree_k, tree_v, tree_mask,
     masks NULL-table positions so the reserved block's contents can never
     leak into the output — matching the kernel's compute-skip exactly.
 
-    q: (B,Hq,T,D); pool_k/v: (N, bs, Hkv, D); block_table: (B, M)."""
-    B = q.shape[0]
-    bs = pool_k.shape[1]
-    M = block_table.shape[1]
-    ck = pool_k[block_table].reshape(B, M * bs, *pool_k.shape[2:])
-    cv = pool_v[block_table].reshape(B, M * bs, *pool_v.shape[2:])
+    q: (B,Hq,T,D); pool_k/v: (N, Hkv, bs, D); block_table: (B, M)."""
+    bs = pool_k.shape[2]
     covered = jnp.repeat(block_table != 0, bs, axis=1)       # (B, M*bs)
-    return tree_attention_ref(q, ck.transpose(0, 2, 1, 3),
-                              cv.transpose(0, 2, 1, 3), tree_k, tree_v,
-                              tree_mask, cache_len, kv_valid=covered)
+    return tree_attention_ref(q, gather_pool_heads(pool_k, block_table),
+                              gather_pool_heads(pool_v, block_table), tree_k,
+                              tree_v, tree_mask, cache_len, kv_valid=covered)
+
+
+def gather_pool_heads(pool, block_table):
+    """Head-major pool (N, Hkv, bs, D) + table (B, M) -> the dense
+    kernel-layout view (B, Hkv, M*bs, D)."""
+    B, M = block_table.shape
+    _, Hkv, bs, D = pool.shape
+    return pool[block_table].transpose(0, 2, 1, 3, 4).reshape(
+        B, Hkv, M * bs, D)
 
 
 def tree_attention_ref(q, cache_k, cache_v, tree_k, tree_v, tree_mask,

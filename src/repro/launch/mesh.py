@@ -21,17 +21,9 @@ def make_host_mesh():
 
 
 def make_abstract_mesh(shape, axes):
-    """Device-free mesh for sharding-rule tests.
-
-    jax changed ``AbstractMesh``'s signature from ``(shape, axis_names)`` to a
-    single ``((name, size), ...)`` tuple around 0.4.36 — accept the old-style
-    arguments and construct whichever form the installed jax expects.
-    """
+    """Device-free mesh for sharding-rule tests."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))
-    except TypeError:
-        return AbstractMesh(shape, axes)
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 # TPU v5e hardware constants for the roofline model (per chip)

@@ -1,32 +1,79 @@
-"""Production serving launcher: one speculative-decoding service per arch.
+"""Serving launcher: one speculative-decoding service per arch.
 
-Container mode runs the reduced config with random weights (smoke);
-cluster mode (--full-config) uses the production mesh shardings from
-launch/specs.py.
+By default it serves the arch's ``reduced()`` config in float32 with
+random weights, a CPU-sized smoke.  ``--full-config`` serves the
+published widths in the config's dtype on one device, unsharded: there
+is no mesh here.  minitron-4b fits one TPU v5e this way, and
+``chip_smoke.py`` drives that path through the same ``build_engine`` and
+``serve`` that ``main`` calls.
 """
 from __future__ import annotations
 
 import argparse
-import time
-
-# CPU-only: the legacy (pre-thunk) XLA CPU runtime serializes pipelined
-# dispatch, which would hide the async serve loop's overlap win in the
-# container smoke runs (see runtime_env; harmless on real accelerators)
-from repro.runtime_env import enable_cpu_thunk_runtime
-
-enable_cpu_thunk_runtime()
+import dataclasses
+from typing import List
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.configs.base import ModelConfig
 from repro.core.heads import init_draft_params
-from repro.core.trees import chain_tree, default_tree
 from repro.launch.specs import tree_for
 from repro.models.model import init_params
-from repro.serving.engine import (BucketedEngine, PagedSpeculativeEngine,
-                                  Request, SpeculativeEngine)
+from repro.runtime_env import use_compilation_cache
+from repro.serving.engine import (BucketedEngine, EngineStats,
+                                  PagedSpeculativeEngine, Request,
+                                  SpeculativeEngine)
+
+
+def random_weights(cfg: ModelConfig, seed: int = 0):
+    """Base and draft-head parameters drawn from ``seed``."""
+    rng = jax.random.PRNGKey(seed)
+    return (init_params(rng, cfg),
+            init_draft_params(jax.random.fold_in(rng, 1), cfg))
+
+
+def build_engine(cfg: ModelConfig, params, draft_params, *,
+                 engine: str = "continuous", max_batch: int = 2,
+                 max_len: int = 512, prefill_chunk: int = 0,
+                 prefill_budget: int = 0, sync: bool = False,
+                 block_size: int = 16, pool_frac: float = 0.5):
+    """The engine ``serve`` drives, with the launcher's defaults.  The
+    paged engine's pool holds ``pool_frac`` of the dense
+    ``max_batch × max_len`` footprint (DESIGN.md §6)."""
+    tree = tree_for(cfg)
+    inflight = 1 if sync else 2
+    chunk_kw = {}
+    if prefill_chunk and engine != "bucketed":
+        chunk_kw = {"prefill_chunk": prefill_chunk,
+                    "prefill_budget": prefill_budget or None}
+    if engine == "paged":
+        usable = max(int(pool_frac * max_batch * max_len) // block_size, 4)
+        return PagedSpeculativeEngine(params, draft_params, cfg, tree,
+                                      max_len=max_len, block_size=block_size,
+                                      num_blocks=usable + 1,
+                                      inflight=inflight, **chunk_kw)
+    if engine == "continuous":
+        return SpeculativeEngine(params, draft_params, cfg, tree,
+                                 max_len=max_len, inflight=inflight,
+                                 **chunk_kw)
+    return BucketedEngine(params, draft_params, cfg, tree, max_len=max_len)
+
+
+def serve(eng, requests: List[Request], *, max_batch: int,
+          stream: bool = False) -> EngineStats:
+    """Serve ``requests`` to completion and return the engine's stats.
+    ``stream`` feeds half of them through the live queue (``submit()`` up
+    front, the rest from a generator source the loop pulls as slots free
+    up) instead of a pre-collected list; the bucketed engine has no live
+    queue and ignores it."""
+    if stream and not isinstance(eng, BucketedEngine):
+        split = max(len(requests) // 2, 1)
+        for r in requests[:split]:
+            eng.submit(r)
+        return eng.serve(source=iter(requests[split:]), max_batch=max_batch)
+    return eng.serve(requests, max_batch=max_batch)
 
 
 def main() -> None:
@@ -68,46 +115,31 @@ def main() -> None:
                     help="paged engine: block-pool size as a fraction of "
                          "the dense max_batch x max_len footprint "
                          "(DESIGN.md §6)")
-    ap.add_argument("--full-config", action="store_true")
+    ap.add_argument("--full-config", action="store_true",
+                    help="serve the published widths in the config's dtype "
+                         "(one device, unsharded)")
     args = ap.parse_args()
+    use_compilation_cache()
 
     cfg = get_config(args.arch)
     if not cfg.supports_decode:
         raise SystemExit(f"{cfg.name} is encoder-only: no decode service "
                          "(DESIGN.md §4)")
     if not args.full_config:
-        import dataclasses
         cfg = dataclasses.replace(cfg.reduced(), dtype="float32")
 
-    rng = jax.random.PRNGKey(0)
-    params = init_params(rng, cfg)
-    dp = init_draft_params(jax.random.fold_in(rng, 1), cfg)
-    tree = tree_for(cfg)
+    params, dp = random_weights(cfg)
+    eng = build_engine(cfg, params, dp, engine=args.engine,
+                       max_batch=args.batch,
+                       prefill_chunk=args.prefill_chunk,
+                       prefill_budget=args.prefill_budget, sync=args.sync,
+                       block_size=args.block_size, pool_frac=args.pool_frac)
+    tree = eng.tree
     print(f"[serve] arch={cfg.name} tree={tree.size} "
           f"(chain={tree.max_depth + 1 == tree.size})")
-
-    max_len = 512
-    inflight = 1 if args.sync else 2
-    chunk_kw = {}
-    if args.prefill_chunk and args.engine != "bucketed":
-        chunk_kw = {"prefill_chunk": args.prefill_chunk,
-                    "prefill_budget": args.prefill_budget or None}
-    if args.engine == "paged":
-        usable = max(int(args.pool_frac * args.batch * max_len)
-                     // args.block_size, 4)
-        eng = PagedSpeculativeEngine(params, dp, cfg, tree, max_len=max_len,
-                                     block_size=args.block_size,
-                                     num_blocks=usable + 1, inflight=inflight,
-                                     **chunk_kw)
-    elif args.engine == "continuous":
-        eng = SpeculativeEngine(params, dp, cfg, tree, max_len=max_len,
-                                inflight=inflight, **chunk_kw)
-    else:
-        eng = BucketedEngine(params, dp, cfg, tree, max_len=max_len)
     rs = np.random.RandomState(0)
-    n_requests = args.requests or args.batch
     reqs = []
-    for i in range(n_requests):
+    for i in range(args.requests or args.batch):
         plen = (rs.randint(max(args.prompt_len // 2, 1), args.prompt_len + 1)
                 if args.ragged else args.prompt_len)
         if args.long_prompts and i % 4 == 0:
@@ -115,16 +147,7 @@ def main() -> None:
         reqs.append(Request(
             prompt=rs.randint(0, cfg.vocab_size, plen).astype(np.int32),
             max_new_tokens=args.max_new_tokens))
-    if args.stream and args.engine != "bucketed":
-        # live-queue path: half the traffic is submitted up front, the
-        # rest arrives through a generator source the loop pulls from as
-        # slots free up (launch/serve is also CI's smoke for this API)
-        split = max(n_requests // 2, 1)
-        for r in reqs[:split]:
-            eng.submit(r)
-        stats = eng.serve(source=iter(reqs[split:]), max_batch=args.batch)
-    else:
-        stats = eng.serve(reqs, max_batch=args.batch)
+    stats = serve(eng, reqs, max_batch=args.batch, stream=args.stream)
     print(f"[serve] engine={args.engine} steps={stats.steps} "
           f"tokens={stats.tokens} tok/step={stats.tokens_per_step:.2f} "
           f"tok/s={stats.tokens_per_s:.1f} "

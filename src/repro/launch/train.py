@@ -19,6 +19,7 @@ from repro.configs import get_config
 from repro.data.synthetic import MarkovSpec, sample_corpus
 from repro.launch.specs import make_train_step
 from repro.models.model import init_params
+from repro.runtime_env import use_compilation_cache
 from repro.training.optim import init_adamw
 
 
@@ -31,6 +32,7 @@ def main() -> None:
     ap.add_argument("--full-config", action="store_true",
                     help="use the production config (needs a real cluster)")
     args = ap.parse_args()
+    use_compilation_cache()
 
     cfg = get_config(args.arch)
     if not args.full_config:

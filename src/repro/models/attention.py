@@ -25,8 +25,10 @@ The verify path speaks two cache layouts (DESIGN.md §6):
 
 * dense: ``cache_k``/``cache_v`` are per-slot (B, S, ...) arrays in
   logical coordinates (``block_table`` None);
-* paged: ``cache_k``/``cache_v`` are the global block pool
-  ``(num_blocks, block_size, ...)`` and ``block_table`` (B, M) maps each
+* paged: ``cache_k``/``cache_v`` are the global block pool — head-major
+  ``(num_blocks, Hkv, block_size, D)`` for GQA, ``(num_blocks,
+  block_size, r)`` for MLA's headless latents; the token axis is always
+  second-to-last — and ``block_table`` (B, M) maps each
   slot's logical token-blocks to physical pool blocks.  New K/V scatter
   through the table at token granularity (O(B·T), no dense transient) and
   attention streams pool blocks natively through the attention-template
@@ -61,7 +63,7 @@ class AttnInputs(NamedTuple):
     """Everything the attention core needs besides x and params."""
 
     q_pos: jnp.ndarray                 # (B, T) absolute positions
-    cache_k: Optional[jnp.ndarray]     # (B, S, Hkv, D), pool (N, bs, Hkv, D)
+    cache_k: Optional[jnp.ndarray]     # (B, S, Hkv, D), pool (N, Hkv, bs, D)
     cache_v: Optional[jnp.ndarray]     # when block_table is set, or None
     cache_len: Optional[jnp.ndarray]   # (B,) valid length
     tree_mask: Optional[jnp.ndarray]   # (T, T) ancestor-or-self bool
@@ -196,17 +198,19 @@ def _prefill_continuation(q, k, v, ai: AttnInputs):
 def _paged_scatter(pool, new, cache_len, block_table):
     """Write T per-token entries into the pool at the scratch region
     ``[cache_len, cache_len + T)``, mapped through the block table.
-    pool: (N, bs, ...); new: (B, T, ...) -> updated pool.  Positions past
+    pool: (N, [Hkv,] bs, D), token axis second-to-last; new: (B, T,
+    [Hkv,] D) -> updated pool.  Positions past
     the table's reach clamp to the last logical slot (the engine
     guarantees coverage for live rows; dead rows' tables are all-NULL, so
     their writes land in the reserved garbage block)."""
-    bs = pool.shape[1]
+    bs = pool.shape[-2]
     M = block_table.shape[1]
     T = new.shape[1]
     logical = cache_len[:, None] + jnp.arange(T)[None, :]            # (B,T)
     logical = jnp.minimum(logical, M * bs - 1)
     phys = jnp.take_along_axis(block_table, logical // bs, axis=1)   # (B,T)
-    return pool.at[phys, logical % bs].set(new.astype(pool.dtype))
+    # (phys, ..., offset, :) indexes as (B, T, [Hkv,] D), matching ``new``
+    return pool.at[phys, ..., logical % bs, :].set(new.astype(pool.dtype))
 
 
 def _paged_gather_layer(pool, table):
@@ -215,9 +219,10 @@ def _paged_gather_layer(pool, table):
     transient, and the only place the pool layout is re-flattened outside
     the shim (serving/paged.py) and the deliberately independent test /
     oracle copies."""
-    bs = pool.shape[1]
+    bs = pool.shape[-2]
     B, M = table.shape
-    view = pool[table].reshape(B, M * bs, *pool.shape[2:])
+    view = jnp.moveaxis(pool[table], -2, 2)            # (B, M, bs, [Hkv,] D)
+    view = view.reshape(B, M * bs, *view.shape[3:])
     covered = jnp.repeat(table != 0, bs, axis=1)
     return view, covered
 

@@ -19,6 +19,7 @@ Execution modes:
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -97,7 +98,12 @@ def _stack_init(fn, key, n):
     return jax.vmap(fn)(keys)
 
 
+@functools.partial(jax.jit, static_argnames="cfg")
 def init_params(key, cfg: ModelConfig):
+    """Random base-model parameters in ``cfg.dtype``.  Built under jit, so
+    each weight is drawn, scaled and cast in one fused pass: no float32
+    copy of a stacked layer matrix is ever materialised, which is what
+    lets a full-width model initialise on the chip that serves it."""
     dtype = jnp.dtype(cfg.dtype)
     keys = jax.random.split(key, 8 + len(group_program(cfg)))
     params: dict = {

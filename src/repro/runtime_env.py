@@ -1,24 +1,29 @@
-"""Process-level runtime knobs that must be set before XLA initializes.
-
-Kept free of jax imports on purpose: entry points call these at the top
-of the module, before anything that could instantiate a backend client.
-"""
+"""Process-level runtime settings shared by the entry points."""
 from __future__ import annotations
 
 import os
 
+import jax
 
-def enable_cpu_thunk_runtime() -> None:
-    """Opt the XLA CPU backend into the thunk runtime (idempotent).
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
-    jax 0.4.37's LEGACY CPU runtime serializes pipelined dispatch — a
-    dispatched computation whose inputs aren't ready yet runs ~2x
-    slower — which inverts the async serve loop's host/device overlap
-    win (DESIGN.md §7).  The thunk runtime (the default on newer
-    jaxlibs) pipelines properly.  No effect on real accelerators, and a
-    no-op if the operator already set the flag either way in XLA_FLAGS.
+# the checkout root (src/repro/runtime_env.py -> ../..)
+_CHECKOUT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                         ".."))
+
+
+def use_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps the cache
+    there and nothing else is set.  Otherwise the cache goes to
+    ``<checkout>/.jax_cache``: a fixed path, because the directory is part
+    of what a later process must find again.  Entry points call this
+    before their first compile; the tests do not.
     """
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_cpu_use_thunk_runtime" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_cpu_use_thunk_runtime=true").strip()
+    path = os.environ.get(CACHE_ENV)
+    if path:
+        return path
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -21,7 +21,8 @@ a host sync inside commit would re-serialize the pipeline it overlaps.
 Commit addresses the cache in LOGICAL coordinates either way.  Dense
 (``block_table`` None): each attention array is the per-slot (B, S) view
 and compaction indexes it directly.  Paged: each attention array is the
-global block pool ``(L, num_blocks, block_size, ...)`` and the (B, M)
+global block pool ``(L, num_blocks, [Hkv,] block_size, D)`` (token axis
+second-to-last, serving/paged.py) and the (B, M)
 block table translates the same logical src/dst positions to (physical
 block, offset) pairs — a token-granular gather/scatter inside slot-owned
 scratch blocks, O(B·D1) touched entries, no dense view in between.
@@ -63,7 +64,7 @@ def _commit_attn(arr, cache_len, path_nodes, *, has_layer_axis: bool,
                  block_table=None):
     """Gather accepted tree slots to the front of the scratch region.
     arr: dense (L,B,S,...) / (B,S,...), or — with ``block_table`` — the
-    pool (L,N,bs,...) / (N,bs,...)."""
+    pool (L,N,[Hkv,]bs,D) / (N,[Hkv,]bs,D)."""
     if not has_layer_axis:
         arr = arr[None]
     D1 = path_nodes.shape[1]
@@ -75,7 +76,7 @@ def _commit_attn(arr, cache_len, path_nodes, *, has_layer_axis: bool,
         vals = arr[:, bidx, src]                           # (L,B,D1,...)
         out = arr.at[:, bidx, dst].set(vals)
     else:
-        bs = arr.shape[2]
+        bs = arr.shape[-2]
         M = block_table.shape[1]
         cap = M * bs
         src = jnp.minimum(cache_len[:, None] + path_nodes, cap - 1)
@@ -83,10 +84,12 @@ def _commit_attn(arr, cache_len, path_nodes, *, has_layer_axis: bool,
                           cap - 1)
         sblk = jnp.take_along_axis(block_table, src // bs, axis=1)  # (B,D1)
         dblk = jnp.take_along_axis(block_table, dst // bs, axis=1)
-        vals = arr[:, sblk, src % bs]                      # (L,B,D1,...)
+        # the same (block, ..., offset) index on both sides, so vals has
+        # exactly the shape the scatter expects
+        vals = arr[:, sblk, ..., src % bs, :]
         # released rows hold all-NULL tables: their writes collide inside
         # the shared garbage block, which is never read unmasked
-        out = arr.at[:, dblk, dst % bs].set(vals)
+        out = arr.at[:, dblk, ..., dst % bs, :].set(vals)
     return out if has_layer_axis else out[0]
 
 

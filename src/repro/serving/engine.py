@@ -156,6 +156,9 @@ class EngineStats:
                      the prefill token are excluded)
     wall_s           wall-clock seconds inside the serving loop (warmup
                      compiles excluded)
+    warmup_s         wall-clock seconds of that warmup: compiling, and
+                     running once, the step and every prefill program the
+                     queued requests need
     host_stall_s     seconds the host spent working while NO step was in
                      flight — i.e. time the host-side harvest/join/
                      allocator bookkeeping STARVED the device pipeline.
@@ -202,6 +205,7 @@ class EngineStats:
     steps: int = 0
     tokens: int = 0
     wall_s: float = 0.0
+    warmup_s: float = 0.0
     host_stall_s: float = 0.0
     read_wait_s: float = 0.0
     steps_in_flight: int = 0
@@ -844,6 +848,7 @@ class SpeculativeEngine(_EngineBase):
         self.rng, sub = jax.random.split(self.rng)
         state = self._init_pool(max_batch, sub)
 
+        t_warm = time.time()
         if warmup:  # compile the step + every join bucket outside the clock
             jax.block_until_ready(self._run_step(
                 state, _snapshot(active)).state.cache_len)
@@ -856,6 +861,7 @@ class SpeculativeEngine(_EngineBase):
                     for fin in (False, True):
                         jax.block_until_ready(
                             self._warm_chunk(state, fin, view).cache_len)
+        self.stats.warmup_s += time.time() - t_warm
 
         # enqueue AFTER warmup so latency measures serving, not XLA
         # compiles (live submit()s carry their own arrival stamp already)
@@ -1336,8 +1342,26 @@ class PagedSpeculativeEngine(SpeculativeEngine):
 
     # -- block accounting ----------------------------------------------------
 
+    def _pool_blocks(self, max_batch: int) -> int:
+        return self.num_blocks or 1 + max_batch * self.blocks_per_slot
+
+    def lower_step(self, max_batch: int):
+        """The decode step ``serve(max_batch=...)`` dispatches, lowered
+        ahead of time: ``.compile().as_text()`` shows the program the chip
+        runs, e.g. whether its attention kernels compiled through Mosaic
+        (``tpu_custom_call``) or were interpreted."""
+        state = jax.eval_shape(lambda: init_paged_state(
+            self.params, self.draft_params, self.cfg, max_batch,
+            self._pool_blocks(max_batch), self.block_size,
+            jax.random.PRNGKey(0)))
+        table = jax.ShapeDtypeStruct((max_batch, self.blocks_per_slot),
+                                     jnp.int32)
+        active = jax.ShapeDtypeStruct((max_batch,), jnp.bool_)
+        return self._step.lower(self.params, self.draft_params, state, table,
+                                active)
+
     def _init_pool(self, max_batch: int, rng):
-        nb = self.num_blocks or 1 + max_batch * self.blocks_per_slot
+        nb = self._pool_blocks(max_batch)
         self._alloc = BlockAllocator(nb, self.block_size)
         B, M = max_batch, self.blocks_per_slot
         self._tables = np.zeros((B, M), np.int32)       # all rows -> NULL

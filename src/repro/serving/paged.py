@@ -5,9 +5,13 @@ not compute — caps ``max_batch``: a slot pays worst-case memory whether
 its request uses it or not.  Paging replaces the per-slot stripes with
 
   * a global **block pool** per attention cache array:
-    ``(L, num_blocks, block_size, ...)`` instead of ``(L, B, max_len, ...)``
-    — persistent HBM is ``num_blocks × block_size`` tokens, which may be
-    far smaller than ``max_batch × max_len`` (oversubscription);
+    ``(L, num_blocks, Hkv, block_size, D)`` instead of the dense
+    ``(L, B, max_len, Hkv, D)`` (MLA's headless latents: ``(L,
+    num_blocks, block_size, r)``) — persistent HBM is ``num_blocks ×
+    block_size`` tokens, which may be far smaller than ``max_batch ×
+    max_len`` (oversubscription).  The pool is head-major so that one
+    (block, head) slab ``(block_size, D)`` is a tile the TPU kernels can
+    DMA: the token axis sits second-to-last in every pool array;
   * a per-slot **block table** ``(B, blocks_per_slot)`` mapping logical
     token-block j of the slot to a physical pool block.  A slot only owns
     blocks for tokens it has actually committed plus the speculative
@@ -60,6 +64,7 @@ from __future__ import annotations
 import heapq
 from typing import Any, List, NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
@@ -146,14 +151,15 @@ class PagedState(NamedTuple):
     """DecodeState with attention caches in pool layout.
 
     ``pools`` mirrors the ``DecodeState.cache`` group structure, but every
-    attention key holds ``(L, num_blocks, block_size, ...)`` and every
-    recurrent-state key keeps its dense per-slot ``(L, B, ...)`` layout.
+    attention key holds ``(L, num_blocks, [Hkv,] block_size, D)`` and
+    every recurrent-state key keeps its dense per-slot ``(L, B, ...)``
+    layout.
     The block table is NOT part of the state — the engine owns it host-side
     and passes it into each jitted step as a ``(B, M)`` int32 operand.
     """
 
     pools: Any
-    prefix_k: Optional[jnp.ndarray]      # (num_blocks, bs, Hkv, hd) or None
+    prefix_k: Optional[jnp.ndarray]      # (num_blocks, Hkv, bs, hd) or None
     prefix_v: Optional[jnp.ndarray]
     cache_len: jnp.ndarray               # (B,)
     last_token: jnp.ndarray              # (B,)
@@ -165,19 +171,22 @@ def init_paged_state(params, draft_params, cfg: ModelConfig, max_batch: int,
                      num_blocks: int, block_size: int, rng) -> PagedState:
     """Empty paged pool: attention caches as block pools, recurrent-state
     groups dense per slot, every row idle."""
-    # init_cache already knows every per-arch group layout: instantiating it
-    # once with (batch=num_blocks, max_len=block_size) yields exactly the
-    # pool shape for attention keys, and once with (batch=max_batch) the
-    # per-slot shape for recurrent-state keys (which carry no seq axis).
-    attn_like = init_cache(cfg, num_blocks, block_size)
+    # init_cache already knows every per-arch group layout: its shapes at
+    # (batch=num_blocks, max_len=block_size) are the pool's up to the
+    # head-major move, and at (batch=max_batch) it gives the per-slot
+    # shape for recurrent-state keys (which carry no seq axis).
+    attn_like = jax.eval_shape(lambda: init_cache(cfg, num_blocks,
+                                                  block_size))
     state_like = init_cache(cfg, max_batch, 1)
     pools = []
     for ga, gs in zip(attn_like, state_like):
-        pools.append({k: (ga[k] if k in ATTN_KEYS else gs[k]) for k in ga})
+        pools.append({k: (_pool_zeros(ga[k], 2) if k in ATTN_KEYS
+                          else gs[k]) for k in ga})
     pk = pv = None
     if draft_params is not None and "prefix" in draft_params:
-        pc = init_prefix_cache(cfg, num_blocks, block_size)
-        pk, pv = pc["k"], pc["v"]
+        pc = jax.eval_shape(lambda: init_prefix_cache(cfg, num_blocks,
+                                                      block_size))
+        pk, pv = _pool_zeros(pc["k"], 1), _pool_zeros(pc["v"], 1)
     return PagedState(
         pools=pools, prefix_k=pk, prefix_v=pv,
         cache_len=jnp.zeros((max_batch,), jnp.int32),
@@ -186,21 +195,35 @@ def init_paged_state(params, draft_params, cfg: ModelConfig, max_batch: int,
         rng=rng)
 
 
+def _pool_zeros(like, seq_axis: int):
+    """Zeros in pool layout for the dense-layout shape ``like``, whose
+    token axis is ``seq_axis``: that axis moves to second-to-last, so GQA's
+    (…, N, bs, Hkv, D) becomes (…, N, Hkv, bs, D) and MLA's (…, N, bs, r)
+    stays as it is."""
+    shape = list(like.shape)
+    tok = shape.pop(seq_axis)
+    shape.insert(len(shape) - 1, tok)
+    return jnp.zeros(shape, like.dtype)
+
+
 def _gather_attn(pool, table):
-    """pool (L, N, bs, *rest) + table (B, M) -> view (L, B, M*bs, *rest)."""
-    L, _, bs = pool.shape[:3]
+    """pool (L, N, [Hkv,] bs, D) + table (B, M) -> dense view
+    (L, B, M*bs, [Hkv,] D)."""
+    L, bs = pool.shape[0], pool.shape[-2]
     B, M = table.shape
-    return pool[:, table].reshape(L, B, M * bs, *pool.shape[3:])
+    view = jnp.moveaxis(pool[:, table], -2, 3)   # (L, B, M, bs, [Hkv,] D)
+    return view.reshape(L, B, M * bs, *view.shape[4:])
 
 
 def _scatter_attn(pool, view, table):
     """Write a dense view back into its pool blocks.  Table entries that
     alias the NULL block receive nondeterministic garbage — by construction
     those regions are never read unmasked."""
-    L, _, bs = pool.shape[:3]
+    L, bs = pool.shape[0], pool.shape[-2]
     B, M = table.shape
+    blocks = view.reshape(L, B, M, bs, *view.shape[3:])
     return pool.at[:, table].set(
-        view.reshape(L, B, M, bs, *pool.shape[3:]).astype(pool.dtype))
+        jnp.moveaxis(blocks, 3, -2).astype(pool.dtype))
 
 
 def gather_view(pstate: PagedState, table) -> DecodeState:

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import resolve_interpret, tpu_compiler_params
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 NULL_BLOCK = 0
@@ -106,7 +106,7 @@ def legacy_flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -210,7 +210,7 @@ def legacy_tree_attention(q, cache_k, cache_v, tree_k, tree_v, tree_mask,
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, T, D), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(cache_len, q, cache_k, cache_v, tree_k, tree_v, tree_mask)
@@ -289,7 +289,7 @@ def legacy_tree_attention_paged(q, pool_k, pool_v, tree_k, tree_v, tree_mask,
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, T, D), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(cache_len, block_table, q, pool_k, pool_v, tree_k, tree_v, tree_mask)

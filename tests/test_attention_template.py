@@ -106,11 +106,14 @@ def test_tree_paged_bit_identity_vs_legacy(rng, bs):
     M = -(-(max(lens) + _T) // bs) + 1
     N = 2 * M + 2
     q, tk, tv, tm, lens = _tree_inputs(rng, 0, lens)
-    pk = _rand(rng, 1, (N, bs, _HKV, _D))
-    pv = _rand(rng, 2, (N, bs, _HKV, _D))
+    pk = _rand(rng, 1, (N, _HKV, bs, _D))
+    pv = _rand(rng, 2, (N, _HKV, bs, _D))
     table = _cover_tables([int(x) for x in lens], _T, bs, M, N)
     new = tree_attention_paged(q, pk, pv, tk, tv, tm, lens, table)
-    old = legacy_tree_attention_paged(q, pk, pv, tk, tv, tm, lens, table)
+    # the frozen kernel reads the old token-major (N, bs, Hkv, D) pool
+    tok_major = lambda p: p.transpose(0, 2, 1, 3)
+    old = legacy_tree_attention_paged(q, tok_major(pk), tok_major(pv), tk,
+                                      tv, tm, lens, table)
     np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
 
 
@@ -123,8 +126,8 @@ def _windowed_case(rng, bs, holes=()):
     lens = [37, 120]
     M = -(-(max(lens) + _T) // bs) + 1
     N = 2 * M + 2
-    pk = _rand(rng, 1, (N, bs, _HKV, _D))
-    pv = _rand(rng, 2, (N, bs, _HKV, _D))
+    pk = _rand(rng, 1, (N, _HKV, bs, _D))
+    pv = _rand(rng, 2, (N, _HKV, bs, _D))
     q, tk, tv, tm, lens_j = _tree_inputs(rng, 0, lens)
     table = _cover_tables(lens, _T, bs, M, N, holes=holes)
     depth = jnp.asarray(default_tree(_T, 2, 3).depth, jnp.int32)

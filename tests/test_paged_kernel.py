@@ -50,11 +50,11 @@ def _cover_tables(lens, T, bs, M, num_blocks, holes=()):
 
 
 def _gathered_view(pool, table):
-    """The dense (B, Hkv, S, D) view the old shim materialized."""
+    """The dense (B, Hkv, S, D) view of a head-major (N, Hkv, bs, D)
+    pool that the old shim materialized."""
     B, M = table.shape
-    bs = pool.shape[1]
-    return pool[table].reshape(B, M * bs, *pool.shape[2:]).transpose(
-        0, 2, 1, 3)
+    _, Hkv, bs, D = pool.shape
+    return pool[table].transpose(0, 2, 1, 3, 4).reshape(B, Hkv, M * bs, D)
 
 
 @pytest.mark.parametrize("bs,M,num_blocks", [(16, 8, 32), (128, 3, 8)])
@@ -66,8 +66,8 @@ def test_paged_parity_vs_dense_kernel_and_ref(rng, bs, M, num_blocks,
     block is partially committed)."""
     B, T, D = 3, 8, 64
     lens = [bs * 2 + 5, 0, min(M * bs - T, bs * 3)]
-    pool_k = _rand(rng, 0, (num_blocks, bs, Hkv, D))
-    pool_v = _rand(rng, 1, (num_blocks, bs, Hkv, D))
+    pool_k = _rand(rng, 0, (num_blocks, Hkv, bs, D))
+    pool_v = _rand(rng, 1, (num_blocks, Hkv, bs, D))
     q = _rand(rng, 2, (B, Hq, T, D))
     tk = _rand(rng, 3, (B, Hkv, T, D))
     tv = _rand(rng, 4, (B, Hkv, T, D))
@@ -95,8 +95,8 @@ def test_paged_null_holes_are_masked(rng):
     would read the NULL block's garbage at the hole."""
     B, Hq, Hkv, T, D, bs, M, N = 2, 2, 2, 8, 64, 16, 6, 16
     lens = [bs * 4, bs * 3 + 7]
-    pool_k = _rand(rng, 10, (N, bs, Hkv, D))
-    pool_v = _rand(rng, 11, (N, bs, Hkv, D))
+    pool_k = _rand(rng, 10, (N, Hkv, bs, D))
+    pool_v = _rand(rng, 11, (N, Hkv, bs, D))
     q = _rand(rng, 12, (B, Hq, T, D))
     tk = _rand(rng, 13, (B, Hkv, T, D))
     tv = _rand(rng, 14, (B, Hkv, T, D))
@@ -124,8 +124,8 @@ def test_null_block_contents_never_influence_output(rng):
     holes below cache_len."""
     B, Hq, Hkv, T, D, bs, M, N = 2, 4, 2, 8, 64, 16, 6, 16
     lens = [bs * 2 + 3, bs * 3]
-    pool_k = _rand(rng, 20, (N, bs, Hkv, D))
-    pool_v = _rand(rng, 21, (N, bs, Hkv, D))
+    pool_k = _rand(rng, 20, (N, Hkv, bs, D))
+    pool_v = _rand(rng, 21, (N, Hkv, bs, D))
     q = _rand(rng, 22, (B, Hq, T, D))
     tk = _rand(rng, 23, (B, Hkv, T, D))
     tv = _rand(rng, 24, (B, Hkv, T, D))
@@ -150,8 +150,8 @@ def test_paged_bshd_wrapper_pads_T(rng):
     tree = default_tree(13, 4, 4)
     tm = jnp.asarray(tree.ancestor_mask)
     lens = [9, bs * 2 + 1]
-    pool_k = _rand(rng, 30, (N, bs, Hkv, D))
-    pool_v = _rand(rng, 31, (N, bs, Hkv, D))
+    pool_k = _rand(rng, 30, (N, Hkv, bs, D))
+    pool_v = _rand(rng, 31, (N, Hkv, bs, D))
     q = _rand(rng, 32, (B, T, Hq, D))
     tk = _rand(rng, 33, (B, T, Hkv, D))
     tv = _rand(rng, 34, (B, T, Hkv, D))
