@@ -325,59 +325,69 @@ def spec_decode_step(params, draft_params, cfg: ModelConfig, tree,
     depth = jnp.asarray(tree.depth)
     tm = jnp.asarray(tree.ancestor_mask)
 
+    # each phase runs under a ``jax.named_scope``: the op metadata, and so
+    # the profiler's view of the step, names it (no effect on the math)
     # 1. draft: populate the candidate tree (root = last_token)
-    tokens, draft_logp = draft_tree_tokens(
-        draft_params, cfg, params, tree, state.last_hidden, state.last_token)
+    with jax.named_scope("draft"):
+        tokens, draft_logp = draft_tree_tokens(
+            draft_params, cfg, params, tree, state.last_hidden,
+            state.last_token)
 
     # 2. verify: one base forward over the T tree tokens
-    positions = state.cache_len[:, None] + depth[None, :]
-    out = forward(params, cfg, tokens, positions, mode="verify",
-                  cache=state.cache, cache_len=state.cache_len, tree_mask=tm,
-                  block_table=block_table)
+    with jax.named_scope("verify"):
+        positions = state.cache_len[:, None] + depth[None, :]
+        out = forward(params, cfg, tokens, positions, mode="verify",
+                      cache=state.cache, cache_len=state.cache_len,
+                      tree_mask=tm, block_table=block_table)
 
     # 3. accept
-    rng, sub = jax.random.split(state.rng)
-    if criterion == "greedy":
-        res = greedy_verify(tree, tokens, out.logits)
-    elif criterion == "typical":
-        res = typical_verify(tree, tokens, out.logits, sub,
-                             temperature=temperature, epsilon=epsilon,
-                             alpha=alpha)
-    else:
-        raise ValueError(criterion)
+    with jax.named_scope("accept"):
+        rng, sub = jax.random.split(state.rng)
+        if criterion == "greedy":
+            res = greedy_verify(tree, tokens, out.logits)
+        elif criterion == "typical":
+            res = typical_verify(tree, tokens, out.logits, sub,
+                                 temperature=temperature, epsilon=epsilon,
+                                 alpha=alpha)
+        else:
+            raise ValueError(criterion)
 
     # 4. commit
-    new_cache = commit_cache(out.cache, state.cache_len, res.path_nodes,
-                             res.n_accept, active=active, prev=state.cache,
-                             block_table=block_table)
-    D1 = res.path_nodes.shape[1]
-    bidx = jnp.arange(B)[:, None]
-    acc_hidden = out.hidden[bidx, res.path_nodes]          # (B, D1, d)
+    with jax.named_scope("commit"):
+        new_cache = commit_cache(out.cache, state.cache_len, res.path_nodes,
+                                 res.n_accept, active=active,
+                                 prev=state.cache, block_table=block_table)
+        D1 = res.path_nodes.shape[1]
+        bidx = jnp.arange(B)[:, None]
+        acc_hidden = out.hidden[bidx, res.path_nodes]      # (B, D1, d)
 
     if draft_params is not None and "prefix" in draft_params:
-        ppos = state.cache_len[:, None] + jnp.arange(D1)[None, :]
-        ph, nk, nv = prefix_forward(
-            draft_params, cfg, acc_hidden, ppos,
-            cache_k=state.prefix_k, cache_v=state.prefix_v,
-            cache_len=state.cache_len, tree_mask=None,     # chain mask
-            block_table=block_table)
-        pk, pv = commit_prefix_cache(nk, nv, state.cache_len, res.path_nodes,
-                                     block_table=block_table)
-        h_next = jnp.take_along_axis(
-            ph, res.n_accept[:, None, None], axis=1)[:, 0]
+        with jax.named_scope("draft_prefix"):
+            ppos = state.cache_len[:, None] + jnp.arange(D1)[None, :]
+            ph, nk, nv = prefix_forward(
+                draft_params, cfg, acc_hidden, ppos,
+                cache_k=state.prefix_k, cache_v=state.prefix_v,
+                cache_len=state.cache_len, tree_mask=None,  # chain mask
+                block_table=block_table)
+            pk, pv = commit_prefix_cache(nk, nv, state.cache_len,
+                                         res.path_nodes,
+                                         block_table=block_table)
+            h_next = jnp.take_along_axis(
+                ph, res.n_accept[:, None, None], axis=1)[:, 0]
     else:
         pk, pv = state.prefix_k, state.prefix_v
         h_next = jnp.take_along_axis(
             acc_hidden, res.n_accept[:, None, None], axis=1)[:, 0]
 
     # 5. emitted tokens this step: accepted candidates then the bonus token
-    tok_path = tokens[bidx, res.path_nodes]                # (B, D1)
-    j = jnp.arange(D1)[None, :]
-    shifted = jnp.concatenate([tok_path[:, 1:],
-                               jnp.full((B, 1), PAD_TOKEN, jnp.int32)], 1)
-    emitted = jnp.where(j < res.n_accept[:, None], shifted, PAD_TOKEN)
-    emitted = jnp.where(j == res.n_accept[:, None], res.bonus_token[:, None],
-                        emitted)
+    with jax.named_scope("commit"):
+        tok_path = tokens[bidx, res.path_nodes]            # (B, D1)
+        j = jnp.arange(D1)[None, :]
+        shifted = jnp.concatenate(
+            [tok_path[:, 1:], jnp.full((B, 1), PAD_TOKEN, jnp.int32)], 1)
+        emitted = jnp.where(j < res.n_accept[:, None], shifted, PAD_TOKEN)
+        emitted = jnp.where(j == res.n_accept[:, None],
+                            res.bonus_token[:, None], emitted)
 
     n_emitted = res.n_accept + 1
     cache_len = state.cache_len + n_emitted

@@ -63,6 +63,7 @@ measurable.
 """
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 import time
@@ -74,6 +75,7 @@ from typing import (Any, Callable, Iterable, List, NamedTuple, Optional,
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models.model import paged_kernel_covers
@@ -87,6 +89,9 @@ from repro.serving.paged import (NULL_BLOCK, BlockAllocator, init_paged_state,
 
 # feeder-thread end-of-stream marker (see SpeculativeEngine._feed_source)
 _SOURCE_DONE = object()
+
+# program names of the (non-final, final) prefill chunk
+_CHUNK_NAMES = {False: "prefill_chunk", True: "prefill_chunk_final"}
 
 
 def _snapshot(host_array: np.ndarray):
@@ -105,6 +110,13 @@ def _snapshot(host_array: np.ndarray):
     return jnp.asarray(host_array.copy())
 
 
+def _named(name: str, fn):
+    """``fn`` under ``name``: ``jax.jit`` calls its XLA module
+    ``jit_<name>``, the name a profiler trace shows for each run."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 @dataclass
 class Request:
     """One generation request.
@@ -113,7 +125,9 @@ class Request:
     token (including the one sampled at prefill) to ``output`` and sets
     ``done`` when the budget is exhausted or ``eos_token`` is produced.
     ``output`` survives preemption: a preempted request resumes by
-    re-prefilling ``prompt + output``.
+    re-prefilling ``prompt + output``.  ``rid`` is assigned by the engine
+    when the request is queued; the profiler spans of its joins, chunks
+    and preemptions carry it.
     """
 
     prompt: np.ndarray
@@ -121,6 +135,7 @@ class Request:
     eos_token: Optional[int] = None
     output: List[int] = field(default_factory=list)
     done: bool = False
+    rid: Optional[int] = None
     # serving timeline (wall-clock seconds, filled in by the engine)
     t_enqueue: Optional[float] = None
     t_join: Optional[float] = None
@@ -173,7 +188,11 @@ class EngineStats:
                      isn't conflated with waiting on compute
     steps_in_flight  high-water mark of dispatched-but-unharvested steps
                      (1 = synchronous loop, 2 = double-buffered)
-    accept_lengths   per-step mean accepted+bonus length over live rows
+    kv_tokens_attended
+                     over harvested steps, the cached context each live
+                     row's verify attended, summed: the row's committed
+                     length (prompt + output - 1) when the step ran, from
+                     the host's own bookkeeping (no device read)
     active_slot_steps / capacity_slot_steps
                      slot-occupancy accounting: capacity counts
                      ``max_batch`` slots per step, active counts rows that
@@ -209,7 +228,7 @@ class EngineStats:
     host_stall_s: float = 0.0
     read_wait_s: float = 0.0
     steps_in_flight: int = 0
-    accept_lengths: List[float] = field(default_factory=list)
+    kv_tokens_attended: int = 0
     active_slot_steps: int = 0
     capacity_slot_steps: int = 0
     request_latency_s: List[float] = field(default_factory=list)
@@ -359,13 +378,15 @@ class _EngineBase:
         self.epsilon = epsilon
         self.rng = jax.random.PRNGKey(seed)
         if use_speculative:
-            self._step = jax.jit(lambda p, dp, st, act: spec_decode_step(
-                p, dp, cfg, tree, st, criterion=criterion,
-                temperature=temperature, epsilon=epsilon, active=act))
+            self._step = jax.jit(_named(
+                "verify_step", lambda p, dp, st, act: spec_decode_step(
+                    p, dp, cfg, tree, st, criterion=criterion,
+                    temperature=temperature, epsilon=epsilon, active=act)))
         else:
-            self._step = jax.jit(lambda p, _dp, st, act: autoregressive_step(
-                p, cfg, st, greedy=(criterion == "greedy"),
-                temperature=temperature, active=act))
+            self._step = jax.jit(_named(
+                "decode_step", lambda p, _dp, st, act: autoregressive_step(
+                    p, cfg, st, greedy=(criterion == "greedy"),
+                    temperature=temperature, active=act)))
         self.stats = EngineStats()
 
     def _run_step(self, state, active=None):
@@ -472,6 +493,7 @@ class SpeculativeEngine(_EngineBase):
         if inflight < 1:
             raise ValueError(f"inflight must be >= 1: {inflight}")
         self.inflight = int(inflight)
+        self._rids = itertools.count()
         self._queue: deque = deque()
         self._inflight: deque = deque()
         self._live_joins: dict = {}          # slot -> (Request, last_token)
@@ -486,17 +508,18 @@ class SpeculativeEngine(_EngineBase):
             or (draft_params is not None and "prefix" in draft_params))
         greedy = self.criterion == "greedy"
         # jit retraces per padded prompt shape, i.e. one compile per bucket
-        self._join_fn = jax.jit(
-            lambda p, dp, st, prompt, rl, slot: join_slot(
-                p, dp, cfg, st, prompt, rl, slot, greedy=greedy))
+        self._join_fn = jax.jit(_named(
+            "join", lambda p, dp, st, prompt, rl, slot: join_slot(
+                p, dp, cfg, st, prompt, rl, slot, greedy=greedy)))
         # chunked prefill compiles one (non-final, final) executable pair
         # per VIEW EXTENT (power-of-two ladder, <= log2(max_len) of them)
         # — independent of how many distinct prompt lengths are served
         self._chunk_fns = {
-            fin: jax.jit(
+            fin: jax.jit(_named(
+                _CHUNK_NAMES[fin],
                 lambda p, dp, st, ch, start, rl, slot, view, _f=fin:
                 join_slot_chunk(p, dp, cfg, st, ch, start, rl, slot,
-                                final=_f, view_len=view, greedy=greedy),
+                                final=_f, view_len=view, greedy=greedy)),
                 static_argnums=7)
             for fin in (False, True)} if prefill_chunk else {}
 
@@ -641,14 +664,18 @@ class SpeculativeEngine(_EngineBase):
         C = self.prefill_chunk
         while si in self._prefills and budget >= C:
             job = self._prefills[si]
-            if not self._grow_prefill(si, job, slots, active, pending):
+            with TraceAnnotation("engine.alloc"):
+                grown = self._grow_prefill(si, job, slots, active, pending)
+            if not grown:
                 break                      # pool dry even after preemption?
             if si not in self._prefills:
                 break                      # _grow_prefill preempted us
             start, end = job.off, job.off + C
             final = end >= len(job.ctx)
-            state = self._dispatch_chunk(state, si, job.ctx[start:end],
-                                         start, job.real_len, final)
+            with TraceAnnotation("engine.prefill_chunk", rid=job.request.rid,
+                                 start=start, final=final):
+                state = self._dispatch_chunk(state, si, job.ctx[start:end],
+                                             start, job.real_len, final)
             self._device_fed()
             job.off = end
             budget -= C
@@ -738,8 +765,13 @@ class SpeculativeEngine(_EngineBase):
         self._check_capacity(r)
         if r.t_enqueue is None:
             r.t_enqueue = time.time()
-        self._queue.append(r)
+        self._enqueue(r)
         return r
+
+    def _enqueue(self, r: Request) -> None:
+        if r.rid is None:
+            r.rid = next(self._rids)
+        self._queue.append(r)
 
     def drain(self, *, max_batch: int = 8, warmup: bool = True
               ) -> EngineStats:
@@ -819,7 +851,7 @@ class SpeculativeEngine(_EngineBase):
               warmup: bool = True) -> EngineStats:
         for r in requests:
             self._check_capacity(r)
-            self._queue.append(r)      # enqueue-stamped after warmup
+            self._enqueue(r)           # enqueue-stamped after warmup
         pending = self._queue
         self._src_done = source is None
         self._src_err: List[BaseException] = []
@@ -887,83 +919,97 @@ class SpeculativeEngine(_EngineBase):
         return self.stats
 
     def _serve_loop(self, pending, max_batch, slots, active, state) -> None:
-        while True:
-            self._poll_source(pending, max_batch)
-            if (not pending and not active.any() and not self._inflight
-                    and not self._prefills and self._src_done):
-                break
+        # profiler spans (DESIGN.md §7): each iteration is one
+        # ``engine.iteration``; its children name the host phases
+        for it in itertools.count():
+            with StepTraceAnnotation("engine.iteration", step_num=it):
+                with TraceAnnotation("engine.poll"):
+                    self._poll_source(pending, max_batch)
+                if (not pending and not active.any() and not self._inflight
+                        and not self._prefills and self._src_done):
+                    break
 
-            # harvest-first policy: give up one step of overlap when the
-            # read buys better scheduling than the overlap is worth —
-            # at a stream's tail (a dispatch could be all-zombie) or when
-            # a likely finish would free a slot/blocks for the queue head
-            while self._inflight and self._harvest_first(pending):
-                self._harvest(self._inflight.popleft())
-
-            # refill every free slot before the next step (strict FIFO: a
-            # head-of-line request the pool can't admit blocks the rest).
-            # Joins/chunks are DISPATCHED into the device lane without
-            # flushing the in-flight step; a join's first sampled token is
-            # read back at harvest, one step behind.
-            joins = []
-            if self.prefill_chunk:
-                # chunked lane (§8): at most prefill_budget prompt tokens
-                # ride alongside this iteration's decode step; a slot only
-                # activates (and joins the step) once its final chunk is in
-                state = self._advance_prefills(state, slots, active,
-                                               pending, joins)
-            else:
-                for si in range(max_batch):
-                    if active[si] or not pending:
-                        continue
-                    if not self._admit(pending[0]):
-                        break
-                    r = pending.popleft()
-                    state = self._join(state, si, r)
-                    self._device_fed()  # prefill queued: device not starved
-                    r.t_join = time.time()
-                    self._live_joins[si] = (r, state.last_token)
-                    joins.append((si, r, state.last_token))
-                    slots[si] = r
-                    active[si] = True
-            # paged: grow block tables for the coming step, preempting the
-            # most-recently-joined slots back into `pending` on exhaustion
-            state = self._before_step(state, slots, active, pending)
-            # a join preempted before its step dispatched was force-read
-            # and requeued by _preempt; drop it from this step's record
-            joins = [(si, r, lt) for si, r, lt in joins
-                     if self._live_joins.get(si, (None,))[0] is r]
-
-            if active.any():
-                res = self._run_step(state, _snapshot(active))
-                self._device_fed()
-                state = res.state
-                self._inflight.append(_StepRecord(
-                    res.emitted, res.n_emitted, active.copy(), list(slots),
-                    joins, max_batch))
-                self.stats.steps_in_flight = max(self.stats.steps_in_flight,
-                                                 len(self._inflight))
-                # double-buffer: harvest step k only once step k+1 is in
-                # the lane (inflight=1 degenerates to the sync loop)
-                while len(self._inflight) >= self.inflight:
+                # harvest-first policy: give up one step of overlap when
+                # the read buys better scheduling than the overlap is
+                # worth — at a stream's tail (a dispatch could be
+                # all-zombie) or when a likely finish would free a
+                # slot/blocks for the queue head
+                while self._inflight and self._harvest_first(pending):
                     self._harvest(self._inflight.popleft())
-            elif self._inflight:
-                # nothing dispatchable: drain the pipeline — harvested
-                # finishes free slots/blocks and may unblock admission
-                self._harvest(self._inflight.popleft())
-            elif self._prefills:
-                # prefill-only interval (e.g. the pool is all long
-                # prompts): chunks are already queued on the device each
-                # iteration — just keep pumping, nothing to harvest yet
-                continue
-            elif pending:
-                raise RuntimeError(
-                    "pool deadlock: no active slots and the queue head "
-                    "cannot be admitted — the block pool is too small "
-                    "for this request stream")
-            else:
-                time.sleep(2e-4)       # idle: waiting on a live source
-                self._starve_t0 = time.time()   # no-traffic idle != stall
+
+                # refill every free slot before the next step (strict
+                # FIFO: a head-of-line request the pool can't admit blocks
+                # the rest).  Joins/chunks are DISPATCHED into the device
+                # lane without flushing the in-flight step; a join's first
+                # sampled token is read back at harvest, one step behind.
+                joins = []
+                with TraceAnnotation("engine.admit"):
+                    if self.prefill_chunk:
+                        # chunked lane (§8): at most prefill_budget prompt
+                        # tokens ride alongside this iteration's decode
+                        # step; a slot only activates (and joins the step)
+                        # once its final chunk is in
+                        state = self._advance_prefills(state, slots, active,
+                                                       pending, joins)
+                    else:
+                        for si in range(max_batch):
+                            if active[si] or not pending:
+                                continue
+                            if not self._admit(pending[0]):
+                                break
+                            r = pending.popleft()
+                            with TraceAnnotation("engine.join", rid=r.rid):
+                                state = self._join(state, si, r)
+                            self._device_fed()  # prefill queued: not starved
+                            r.t_join = time.time()
+                            self._live_joins[si] = (r, state.last_token)
+                            joins.append((si, r, state.last_token))
+                            slots[si] = r
+                            active[si] = True
+                # paged: grow block tables for the coming step, preempting
+                # the most-recently-joined slots back into `pending` on
+                # exhaustion
+                with TraceAnnotation("engine.alloc"):
+                    state = self._before_step(state, slots, active, pending)
+                # a join preempted before its step dispatched was
+                # force-read and requeued by _preempt; drop it from this
+                # step's record
+                joins = [(si, r, lt) for si, r, lt in joins
+                         if self._live_joins.get(si, (None,))[0] is r]
+
+                if active.any():
+                    with TraceAnnotation("engine.dispatch"):
+                        res = self._run_step(state, _snapshot(active))
+                    self._device_fed()
+                    state = res.state
+                    self._inflight.append(_StepRecord(
+                        res.emitted, res.n_emitted, active.copy(),
+                        list(slots), joins, max_batch))
+                    self.stats.steps_in_flight = max(
+                        self.stats.steps_in_flight, len(self._inflight))
+                    # double-buffer: harvest step k only once step k+1 is
+                    # in the lane (inflight=1 degenerates to the sync loop)
+                    while len(self._inflight) >= self.inflight:
+                        self._harvest(self._inflight.popleft())
+                elif self._inflight:
+                    # nothing dispatchable: drain the pipeline — harvested
+                    # finishes free slots/blocks and may unblock admission
+                    self._harvest(self._inflight.popleft())
+                elif self._prefills:
+                    # prefill-only interval (e.g. the pool is all long
+                    # prompts): chunks are already queued on the device
+                    # each iteration — just keep pumping, nothing to
+                    # harvest yet
+                    continue
+                elif pending:
+                    raise RuntimeError(
+                        "pool deadlock: no active slots and the queue head "
+                        "cannot be admitted — the block pool is too small "
+                        "for this request stream")
+                else:
+                    with TraceAnnotation("engine.idle"):
+                        time.sleep(2e-4)   # waiting on a live source
+                    self._starve_t0 = time.time()  # no-traffic idle != stall
 
     def _stop_feeder(self) -> None:
         if self._src_thread is not None:
@@ -1043,10 +1089,17 @@ class SpeculativeEngine(_EngineBase):
         requests it ran over (snapshotted in ``rec`` — host scheduling has
         moved on since dispatch).  This is the ONLY place the serve loop
         blocks on the device."""
-        t0 = time.time()
-        emitted = np.asarray(rec.emitted)           # blocks until the step
-        n_em = np.asarray(rec.n_emitted)            # (and its joins) are done
-        self.stats.read_wait_s += time.time() - t0
+        with TraceAnnotation("engine.read"):
+            t0 = time.time()
+            emitted = np.asarray(rec.emitted)       # blocks until the step
+            n_em = np.asarray(rec.n_emitted)        # (and its joins) are done
+            self.stats.read_wait_s += time.time() - t0
+        with TraceAnnotation("engine.harvest"):
+            self._apply_step(rec, emitted, n_em)
+
+    def _apply_step(self, rec: _StepRecord, emitted: np.ndarray,
+                    n_em: np.ndarray) -> None:
+        """The host bookkeeping of one harvested step."""
         if not self._inflight and self._starve_t0 is None:
             # pipeline drained: host bookkeeping from here to the next
             # dispatch serializes with the (idle) device
@@ -1066,6 +1119,9 @@ class SpeculativeEngine(_EngineBase):
             r = rec.slots[si]
             if not r.done:
                 live += 1
+                # the row's committed length when the step ran
+                self.stats.kv_tokens_attended += (len(r.prompt)
+                                                  + len(r.output) - 1)
                 if self._slots[si] is r:    # still owns the slot (it may
                     self._advance(si, int(n_em[si]))   # have been preempted)
                 appended = 0
@@ -1091,8 +1147,6 @@ class SpeculativeEngine(_EngineBase):
                 self._active[si] = False
                 self._release(si)
         self.stats.steps += 1
-        if rec.active.any():
-            self.stats.accept_lengths.append(float(n_em[rec.active].mean()))
         self.stats.active_slot_steps += live
         self.stats.capacity_slot_steps += rec.max_batch
 
@@ -1116,9 +1170,10 @@ class SpeculativeEngine(_EngineBase):
         if ent is None:
             return
         r, last_tok = ent
-        t0 = time.time()
-        tok0 = int(np.asarray(last_tok)[si])
-        self.stats.read_wait_s += time.time() - t0
+        with TraceAnnotation("engine.read"):
+            t0 = time.time()
+            tok0 = int(np.asarray(last_tok)[si])
+            self.stats.read_wait_s += time.time() - t0
         self._absorb_first_token(r, tok0)
 
     def _drain_slot(self, si: int, r: Request) -> None:
@@ -1204,29 +1259,32 @@ class PagedSpeculativeEngine(SpeculativeEngine):
         greedy = self.criterion == "greedy"
         cfg_, tree_ = self.cfg, self.tree
         if self.use_speculative:
-            self._step = jax.jit(
+            self._step = jax.jit(_named(
+                "verify_step",
                 lambda p, dp, st, tbl, act: paged_spec_decode_step(
                     p, dp, cfg_, tree_, st, tbl, criterion=self.criterion,
                     temperature=self.temperature, epsilon=self.epsilon,
-                    active=act, attention=paged_attention))
+                    active=act, attention=paged_attention)))
         else:
-            self._step = jax.jit(
+            self._step = jax.jit(_named(
+                "decode_step",
                 lambda p, _dp, st, tbl, act: paged_autoregressive_step(
                     p, cfg_, st, tbl, greedy=greedy,
                     temperature=self.temperature, active=act,
-                    attention=paged_attention))
-        self._join_fn = jax.jit(
-            lambda p, dp, st, prompt, rl, slot, row: paged_join_slot(
-                p, dp, cfg_, st, prompt, rl, slot, row, greedy=greedy))
+                    attention=paged_attention)))
+        self._join_fn = jax.jit(_named(
+            "join", lambda p, dp, st, prompt, rl, slot, row: paged_join_slot(
+                p, dp, cfg_, st, prompt, rl, slot, row, greedy=greedy)))
         # chunked prefill writes straight through the block table — the
         # per-slot dense join strip never exists on this path (§8).  The
         # view extent arrives as a static TABLE-ROW truncation (blocks)
         self._chunk_fns = {
-            fin: jax.jit(
+            fin: jax.jit(_named(
+                _CHUNK_NAMES[fin],
                 lambda p, dp, st, ch, start, rl, slot, row, vb, _f=fin:
                 paged_join_slot_chunk(p, dp, cfg_, st, ch, start, rl, slot,
                                       row, final=_f, view_blocks=vb,
-                                      greedy=greedy),
+                                      greedy=greedy)),
                 static_argnums=8)
             for fin in (False, True)} if self.prefill_chunk else {}
 
@@ -1333,7 +1391,8 @@ class PagedSpeculativeEngine(SpeculativeEngine):
             victims = [s for s in range(len(slots))
                        if active[s] or s in self._prefills]
             victim = max(victims, key=lambda s: self._join_seq[s])
-            self._preempt(int(victim), slots, active, pending)
+            with TraceAnnotation("engine.preempt", rid=slots[victim].rid):
+                self._preempt(int(victim), slots, active, pending)
             if victim == si:
                 return False
 
@@ -1447,7 +1506,9 @@ class PagedSpeculativeEngine(SpeculativeEngine):
                 victims = [s for s in range(len(slots))
                            if active[s] or s in self._prefills]
                 victim = max(victims, key=lambda s: self._join_seq[s])
-                self._preempt(int(victim), slots, active, pending)
+                with TraceAnnotation("engine.preempt",
+                                     rid=slots[victim].rid):
+                    self._preempt(int(victim), slots, active, pending)
         return state
 
     def _preempt(self, si: int, slots, active, pending) -> None:
@@ -1588,6 +1649,8 @@ class BucketedEngine(_EngineBase):
             for bi, r in enumerate(batch):
                 if r.done:
                     continue  # finished rows keep stepping but emit nothing
+                self.stats.kv_tokens_attended += (len(r.prompt)
+                                                  + len(r.output) - 1)
                 appended = 0
                 for t in emitted[bi][:n_em[bi]]:
                     if len(r.output) >= r.max_new_tokens:
@@ -1603,8 +1666,6 @@ class BucketedEngine(_EngineBase):
                 if r.done or len(r.output) >= r.max_new_tokens:
                     self._finish(r)
             self.stats.steps += 1
-            if live.any():  # acceptance/occupancy over live rows only
-                self.stats.accept_lengths.append(float(n_em[live].mean()))
             self.stats.active_slot_steps += int(live.sum())
             self.stats.capacity_slot_steps += max_batch
             produced += int(n_em.min()) if n_em.size else 1
