@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.kernels import tuned_block_sizes
 from repro.kernels.attention_template.kernel import (TemplateSpec,
+                                                     paged_defaults,
                                                      tree_attention_template)
 
 
@@ -38,6 +39,7 @@ def tree_attention_paged_windowed_bshd(q, pool_k, pool_v, tree_k, tree_v,
                                        tree_mask, cache_len, block_table,
                                        q_pos, window, *,
                                        pad_to: int | None = None,
+                                       ppb: int | None = None,
                                        interpret: bool | None = None):
     """Sliding-window tree verification streaming K/V from the block pool.
 
@@ -47,13 +49,16 @@ def tree_attention_paged_windowed_bshd(q, pool_k, pool_v, tree_k, tree_v,
     <= 0 means full attention, so one compiled kernel serves a scan
     group mixing local and global layers).  Precondition: every real
     query row sits at ``q_pos >= cache_len`` (verify positions are
-    ``cache_len + depth``).  Returns (B, T, Hq, D).
+    ``cache_len + depth``).  pad_to/ppb: None => autotuned winners
+    (``paged_defaults`` without one), looked up once and only for what
+    is None.  Returns (B, T, Hq, D).
     """
-    D = q.shape[-1]
-    bs = pool_k.shape[2]
-    if pad_to is None:
-        pad_to = tuned_block_sizes("tree_paged_windowed", D, block_size=bs,
-                                   defaults={"pad_to": 8})["pad_to"]
+    if pad_to is None or ppb is None:
+        bs = pool_k.shape[2]
+        tuned = tuned_block_sizes("tree_paged_windowed", q.shape[-1],
+                                  block_size=bs, defaults=paged_defaults(bs))
+        pad_to = tuned["pad_to"] if pad_to is None else pad_to
+        ppb = tuned["ppb"] if ppb is None else ppb
     T = q.shape[1]
     Tp = -(-T // pad_to) * pad_to
     q, tree_k, tree_v, q_pos = (_pad_axis1(t, Tp)
@@ -64,7 +69,7 @@ def tree_attention_paged_windowed_bshd(q, pool_k, pool_v, tree_k, tree_v,
         tr(q), pool_k, pool_v, tr(tree_k), tr(tree_v), tm, cache_len,
         block_table, window, q_pos,
         spec=TemplateSpec(kind="tree", layout="paged", windowed=True),
-        interpret=interpret)
+        ppb=ppb, interpret=interpret)
     return tr(o)[:, :T]
 
 

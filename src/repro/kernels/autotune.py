@@ -8,9 +8,10 @@ allocator block_size]) and records the winners in
 * ``flash``                — ``(bq, bk)`` tile grid of the self family;
 * ``tree_dense``           — cache strip ``bk`` + the tree-axis ``pad_to``
   (the padded T is the tree family's "query block");
-* ``tree_paged`` / ``tree_paged_windowed`` / ``mla_paged`` — ``pad_to``
-  only: the kv tile is pinned to the allocator's ``block_size``, which
-  therefore joins the cache key.
+* ``tree_paged`` / ``tree_paged_windowed`` / ``mla_paged`` — ``pad_to``:
+  the page is pinned to the allocator's ``block_size``, which therefore
+  joins the cache key; the two page-walking variants also sweep their
+  pages per compute block ``ppb``, sized in tokens (``candidates``).
 
 CLI (also the CI surface — the nightly sweeps and checks, pushes stay on
 the committed cache):
@@ -53,6 +54,22 @@ CANDIDATES = {
     "tree_paged_windowed": [{"pad_to": p} for p in (8, 16, 32)],
     "mla_paged": [{"pad_to": p} for p in (8, 16, 32)],
 }
+
+# tokens per compute block of the paged walk: ppb = tokens // block_size
+_WALK_BLOCK_TOKENS = (128, 256)
+
+
+def candidates(variant: str, block_size: int | None = None) -> list[dict]:
+    """The candidate grid of one key.  The page-walking variants add
+    ``ppb`` to each ``pad_to``, from block sizes in tokens, so that every
+    allocator block size gets blocks of about the same tokens."""
+    cands = CANDIDATES[variant]
+    if variant not in ("tree_paged", "tree_paged_windowed"):
+        return cands
+    ppbs = sorted({max(1, t // (block_size or 16))
+                   for t in _WALK_BLOCK_TOKENS})
+    return [dict(c, ppb=k) for c in cands for k in ppbs]
+
 
 # sweep workload (modest: the CPU interpret path is the common case)
 _B, _HQ, _HKV, _T, _S = 2, 4, 2, 13, 512
@@ -159,7 +176,7 @@ def sweep_entry(variant: str, head_dim: int,
     """Time every candidate for one key; return the winner entry
     (winning sizes + the full candidate->us table)."""
     results = {}
-    for cand in CANDIDATES[variant]:
+    for cand in candidates(variant, block_size):
         label = "x".join(str(v) for v in cand.values())
         results[label] = (_time(_bench_fn(variant, head_dim, block_size,
                                           cand)), cand)

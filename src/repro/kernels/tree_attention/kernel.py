@@ -9,36 +9,39 @@ points here are thin instantiations of ``kernels/attention_template``
 
 TPU-native design (vs the GPU approach of materializing a (T, S) additive
 mask): the cache sweep is mask-free except for a per-block validity clamp
-(k_pos < cache_len, via scalar prefetch), streamed HBM->VMEM in bk-sized
-blocks with online softmax; the static (T, T) ancestor mask only touches
-the final grid step.
+(k_pos < cache_len, via scalar prefetch), streamed HBM->VMEM in blocks
+with online softmax; the static (T, T) ancestor mask only touches the
+tree tokens, folded in last.
 
-Two cache layouts share the same sweep:
+Two cache layouts share the online softmax:
 
-* ``tree_attention``      — dense per-slot cache ``(B, Hkv, S, D)``; the
-  grid's cache axis walks S in ``bk``-sized strips.  ``bk=None`` takes
-  the autotuned winner (key ``tree_dense|hd=<D>``); sizes that don't
-  tile S are legalized by pad-or-clamp instead of asserting.
+* ``tree_attention``      — dense per-slot cache ``(B, Hkv, S, D)``; grid
+  ``(B, Hq, S/bk + 1)``, the cache axis walking S in ``bk``-sized strips.
+  ``bk=None`` takes the autotuned winner (key ``tree_dense|hd=<D>``);
+  sizes that don't tile S are legalized by pad-or-clamp instead of
+  asserting.
 * ``tree_attention_paged`` — vLLM-style global block pool, head-major
   ``(num_blocks, Hkv, block_size, D)``, plus a per-slot block table
-  ``(B, M)``; the grid's cache axis walks *table entries*, each index map
-  scalar-prefetches ``block_table[b, j]`` so K/V blocks stream straight
-  from the pool with no dense intermediate.  NULL-table entries (physical
-  block 0) and entries past ``cache_len`` are compute-skipped, giving
-  ragged early-exit for short slots; runs of skipped entries all map to
-  block 0, so Mosaic's revisit elision drops their copies after the first.
-  The cache tile here is the ALLOCATOR's ``block_size`` (sublane axis:
-  must be a multiple of 8 — ValueError otherwise; bf16 pools want a
-  multiple of 16, and larger blocks fill more of the MXU).
-
-Grid: (B, Hq, n_cache_blocks + 1), innermost 'arbitrary' (sequential).
+  ``(B, M)``; grid ``(B,)``, one program per row, in which the G query
+  heads of each KV head form one ``(G*T, D)`` tile.  The body walks only
+  the row's live pages, ``ceil(cache_len / block_size)`` of them, ``ppb``
+  to a compute block: each page, all KV heads at once, is DMA'd from the
+  pool in HBM into a double-buffered VMEM tile through
+  ``block_table[b, p]``, the next block in flight while this one
+  computes.  NULL entries (physical block 0) are
+  never copied and are masked; pages past ``cache_len`` cost nothing.
+  A page is the ALLOCATOR's ``block_size`` (sublane axis: must be a
+  multiple of 8 — ValueError otherwise; bf16 pools want a multiple of
+  16).  ``ppb`` and the tree axis' ``pad_to`` are resolved together,
+  once, by ``tree_attention_paged_bshd``: the autotuned winner (key
+  ``tree_paged|hd=<D>|bs=<block_size>``), else ``paged_defaults``, about
+  128 tokens a block.
 """
 from __future__ import annotations
 
 from repro.kernels import tuned_block_sizes
-from repro.kernels.attention_template.kernel import (  # noqa: F401
-    NEG_INF, NULL_BLOCK, TemplateSpec, _init_scratch, _softmax_update,
-    tree_attention_template)
+from repro.kernels.attention_template.kernel import (
+    TemplateSpec, paged_defaults, tree_attention_template)
 
 _DENSE_DEFAULTS = {"bk": 512}
 
@@ -60,7 +63,7 @@ def tree_attention(q, cache_k, cache_v, tree_k, tree_v, tree_mask, cache_len,
 
 
 def tree_attention_paged(q, pool_k, pool_v, tree_k, tree_v, tree_mask,
-                         cache_len, block_table, *,
+                         cache_len, block_table, *, ppb: int | None = None,
                          interpret: bool | None = None):
     """Tree verification streaming K/V from a paged block pool.
 
@@ -68,19 +71,24 @@ def tree_attention_paged(q, pool_k, pool_v, tree_k, tree_v, tree_mask,
     global head-major pool, NOT a per-slot view; tree_k/v: (B,Hkv,T,D);
     tree_mask: (T,T) bool ancestor-or-self; cache_len: (B,) int32
     committed length per slot; block_table: (B, M) int32 physical block ids (0 = NULL).
+    ppb: pages per compute block; None => the built-in default, about 128
+    tokens (``paged_defaults``; ``tree_attention_paged_bshd`` resolves the
+    autotuned winner).  ppb=1 updates page by page, as the per-entry grid
+    did.
     interpret: None => auto (compile on TPU, interpret elsewhere).
     Returns (B,Hq,T,D).
 
-    The grid's cache axis has one step per table entry: the index map
-    scalar-prefetches ``block_table[b, j]``, so the per-step HBM traffic
-    is exactly the blocks the slot owns below ``cache_len`` (plus the T
-    tree tokens) — O(blocks touched), never O(B x max_len).  Positions
-    inside the last committed block but >= cache_len are clamped in-body;
-    NULL entries (holes or the unallocated tail) are compute-skipped and
-    their contents can never reach the output.
+    Each row's program copies exactly the pages the slot owns below
+    ``cache_len`` (plus the T tree tokens) — O(blocks touched), never
+    O(B x max_len), each page once for all its query heads.
+    Positions inside the last committed page but >= cache_len are masked
+    in-body; NULL entries (holes or the unallocated tail) are never
+    copied and their contents can never reach the output.
     """
+    if ppb is None:
+        ppb = paged_defaults(pool_k.shape[2])["ppb"]
     return tree_attention_template(
         q, pool_k, pool_v, tree_k, tree_v, tree_mask, cache_len,
         block_table=block_table,
-        spec=TemplateSpec(kind="tree", layout="paged"),
+        spec=TemplateSpec(kind="tree", layout="paged"), ppb=ppb,
         interpret=interpret)

@@ -31,6 +31,7 @@ from repro.kernels import autotune_cache_path, block_size_key
 from repro.kernels.attention_template import (mla_attention_paged_bshd,
                                               self_attention,
                                               tree_attention_paged_windowed_bshd)
+from repro.kernels.attention_template.kernel import paged_defaults
 from repro.kernels.attention_template.ref import (
     mla_attention_paged_ref, tree_attention_paged_windowed_ref)
 from repro.kernels.flash_attention.kernel import flash_attention
@@ -38,7 +39,8 @@ from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.tree_attention.kernel import (tree_attention,
                                                  tree_attention_paged)
 from repro.kernels.tree_attention.ops import tree_attention_paged_bshd
-from repro.kernels.tree_attention.ref import tree_attention_ref
+from repro.kernels.tree_attention.ref import (tree_attention_paged_ref,
+                                              tree_attention_ref)
 from repro.core.trees import default_tree
 
 _B, _HQ, _HKV, _T, _D = 2, 4, 2, 13, 64
@@ -51,7 +53,7 @@ def _rand(key, i, shape):
 def _cover_tables(lens, T, bs, M, num_blocks, holes=()):
     """Per-slot block tables covering lens[b]+T tokens; optional holes
     are NULL entries inside the covered range."""
-    table = np.zeros((_B, M), np.int32)
+    table = np.zeros((len(lens), M), np.int32)
     nxt = 1
     for b, L in enumerate(lens):
         for j in range(-(-(int(L) + T) // bs)):
@@ -109,12 +111,61 @@ def test_tree_paged_bit_identity_vs_legacy(rng, bs):
     pk = _rand(rng, 1, (N, _HKV, bs, _D))
     pv = _rand(rng, 2, (N, _HKV, bs, _D))
     table = _cover_tables([int(x) for x in lens], _T, bs, M, N)
-    new = tree_attention_paged(q, pk, pv, tk, tv, tm, lens, table)
+    # one page per compute block: the per-page update order of the old grid
+    new = tree_attention_paged(q, pk, pv, tk, tv, tm, lens, table, ppb=1)
     # the frozen kernel reads the old token-major (N, bs, Hkv, D) pool
     tok_major = lambda p: p.transpose(0, 2, 1, 3)
     old = legacy_tree_attention_paged(q, tok_major(pk), tok_major(pv), tk,
                                       tv, tm, lens, table)
     np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
+
+
+# Rows at the edges of the paged walk (block size 16): cache_len, and the
+# first table entry from which the row's table is NULL (None: covered).
+_WALK_ROWS = [
+    (0, None),      # empty: straight to the tree step
+    (37, None),     # ends mid-page, inside the first compute block
+    (300, None),    # longer than one 128-token block, not a multiple of it
+    (256, None),    # NULL holes, one filling a whole block (below)
+    (200, 10),      # its live range ends in NULL entries: the tail is NULL
+]
+_WALK_HOLES = [(3, 1), (3, 8), (3, 9), (3, 10), (3, 11), (3, 12), (3, 13),
+               (3, 14), (3, 15), (2, 3)]
+
+
+@pytest.mark.parametrize("ppb", [paged_defaults(16)["ppb"], 3])
+def test_tree_paged_walk_matches_ref(rng, ppb):
+    """The served pages-per-block (and an odd one) against the oracle on
+    every edge of the walk: an empty row, a row ending mid-page, rows
+    longer than a block and not a multiple of it, NULL holes (one a whole
+    block at the served ppb) and a row whose table tail is NULL."""
+    bs = 16
+    lens = [L for L, _ in _WALK_ROWS]
+    B = len(lens)
+    M = -(-(max(lens) + _T) // bs) + 1
+    N = 1 + B * M
+    table = np.zeros((B, M), np.int32)
+    nxt = 1
+    for b, (L, null_from) in enumerate(_WALK_ROWS):
+        for j in range(-(-(L + _T) // bs)):
+            table[b, j] = nxt
+            nxt += 1
+        if null_from is not None:
+            table[b, null_from:] = 0
+    for b, j in _WALK_HOLES:
+        table[b, j] = 0
+    table = jnp.asarray(table)
+    q = _rand(rng, 40, (B, _HQ, _T, _D))
+    tk = _rand(rng, 41, (B, _HKV, _T, _D))
+    tv = _rand(rng, 42, (B, _HKV, _T, _D))
+    pk = _rand(rng, 43, (N, _HKV, bs, _D))
+    pv = _rand(rng, 44, (N, _HKV, bs, _D))
+    tm = jnp.asarray(np.asarray(default_tree(_T, 2, 3).ancestor_mask))
+    lens = jnp.asarray(lens, jnp.int32)
+    out = tree_attention_paged(q, pk, pv, tk, tv, tm, lens, table, ppb=ppb)
+    ref = tree_attention_paged_ref(q, pk, pv, tk, tv, tm, lens, table)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -144,6 +195,42 @@ def test_windowed_paged_matches_ref(rng, bs, window):
         q.transpose(0, 2, 1, 3), pk, pv, tk.transpose(0, 2, 1, 3),
         tv.transpose(0, 2, 1, 3), tm, lens, table, q_pos, w)
     ref = tree_attention_paged_windowed_ref(q, pk, pv, tk, tv, tm, lens,
+                                            table, q_pos, w)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref.transpose(0, 2, 1, 3)),
+                               rtol=2e-5, atol=2e-5)
+
+
+# Windowed rows whose walk starts past page 0 and, at ppb 3 or a 200-token
+# window, runs on to a second compute block (block size 16): cache_len,
+# with NULL holes inside the walked ranges.
+_WINDOW_LENS = [300, 300, 261, 37]
+_WINDOW_HOLES = [(0, 17), (2, 14)]
+
+
+@pytest.mark.parametrize("ppb", [paged_defaults(16)["ppb"], 3])
+@pytest.mark.parametrize("window", [24, 64, 200])
+def test_windowed_paged_walk_matches_ref(rng, window, ppb):
+    bs = 16
+    lens = _WINDOW_LENS
+    B = len(lens)
+    M = -(-(max(lens) + _T) // bs) + 1
+    N = 1 + B * M
+    table = _cover_tables(lens, _T, bs, M, N, holes=_WINDOW_HOLES)
+    q = _rand(rng, 50, (B, _HQ, _T, _D))
+    tk = _rand(rng, 51, (B, _HKV, _T, _D))
+    tv = _rand(rng, 52, (B, _HKV, _T, _D))
+    pk = _rand(rng, 53, (N, _HKV, bs, _D))
+    pv = _rand(rng, 54, (N, _HKV, bs, _D))
+    tm = jnp.asarray(np.asarray(default_tree(_T, 2, 3).ancestor_mask))
+    lens_j = jnp.asarray(lens, jnp.int32)
+    depth = jnp.asarray(default_tree(_T, 2, 3).depth, jnp.int32)
+    q_pos = lens_j[:, None] + depth[None, :]
+    w = jnp.int32(window)
+    out = tree_attention_paged_windowed_bshd(
+        q.transpose(0, 2, 1, 3), pk, pv, tk.transpose(0, 2, 1, 3),
+        tv.transpose(0, 2, 1, 3), tm, lens_j, table, q_pos, w, ppb=ppb)
+    ref = tree_attention_paged_windowed_ref(q, pk, pv, tk, tv, tm, lens_j,
                                             table, q_pos, w)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.transpose(0, 2, 1, 3)),
@@ -245,6 +332,51 @@ def test_flash_multiple_block_points_including_autotuned(rng):
         out = flash_attention(q, k, v, window=64, bq=bq, bk=bk)
         np.testing.assert_allclose(np.asarray(out), np.asarray(base),
                                    rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("variant", ["tree_paged", "tree_paged_windowed"])
+def test_sweep_mode_resweeps_a_missing_paged_key_once(rng, monkeypatch,
+                                                      tmp_path, variant):
+    """``REPRO_AUTOTUNE=sweep`` against a cache without the key: the
+    wrapper's lookup sweeps it once, and timing a candidate, which passes
+    every block size, never looks the key up again (no recursion)."""
+    import repro.kernels as kernels
+    from repro.kernels import autotune
+
+    cache = tmp_path / "autotune.json"
+    cache.write_text(json.dumps({"format": 1, "entries": {}}))
+    monkeypatch.setenv("REPRO_AUTOTUNE", "sweep")
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(cache))
+    monkeypatch.setattr(kernels, "_swept_keys", {})
+    # one cheap candidate, timed once: the point is the call graph
+    monkeypatch.setitem(autotune.CANDIDATES, variant, [{"pad_to": 16}])
+    monkeypatch.setattr(autotune, "_WALK_BLOCK_TOKENS", (32,))
+    monkeypatch.setattr(autotune, "_S", 64)
+    monkeypatch.setattr(autotune, "_WARMUP", 0)
+    monkeypatch.setattr(autotune, "_REPS", 1)
+    sweeps = []
+    real = autotune.sweep_entry
+    monkeypatch.setattr(autotune, "sweep_entry",
+                        lambda *a, **k: sweeps.append(a) or real(*a, **k))
+
+    bs = 16
+    q, pk, pv, tk, tv, tm, lens, table, q_pos = _windowed_case(rng, bs)
+    args = (q.transpose(0, 2, 1, 3), pk, pv, tk.transpose(0, 2, 1, 3),
+            tv.transpose(0, 2, 1, 3), tm, lens, table)
+    if variant == "tree_paged":
+        out = tree_attention_paged_bshd(*args)
+        ref = tree_attention_paged_ref(q, pk, pv, tk, tv, tm, lens, table)
+    else:
+        w = jnp.int32(24)
+        out = tree_attention_paged_windowed_bshd(*args, q_pos, w)
+        ref = tree_attention_paged_windowed_ref(q, pk, pv, tk, tv, tm, lens,
+                                                table, q_pos, w)
+    assert sweeps == [(variant, _D)]
+    entry = kernels._swept_keys[block_size_key(variant, _D, bs)]
+    assert (entry["pad_to"], entry["ppb"]) == (16, 2)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref.transpose(0, 2, 1, 3)),
+                               rtol=2e-5, atol=2e-5)
 
 
 def test_self_attention_legalizes_odd_lengths(rng):
