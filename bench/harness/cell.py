@@ -7,6 +7,13 @@ requests through the engine's own warm-up, sized so that every program
 the window can run is compiled: the longest prompt the mix can send plus
 its output (which is what a preempted request resumes with), so every
 chunk view up to the row's capacity, and the verify step.
+
+What depends on the model's kind (the check of the program's registered
+configuration, the plain reference, the verify step's FLOPs and the tree
+kernel's work) comes from ``bench/kinds/<model.kind>.py``, so a
+configuration joins the benchmark with new files alone: its file under
+``bench/configs/``, its kind's module where the kind is new, its mix
+under ``bench/traffic/``, and entries in ``BENCHMARK.json``.
 """
 from __future__ import annotations
 
@@ -29,6 +36,7 @@ from harness.peaks import peaks_for
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
+KINDS_DIR = os.path.join(BENCH_DIR, "kinds")
 
 
 class NoChip(SystemExit):
@@ -67,6 +75,17 @@ def mix_file(name: str) -> dict:
                                          name + ".json"))
 
 
+def kind_module(conf: dict):
+    """The module of the configuration's ``model.kind``:
+    ``bench/kinds/<kind>.py`` (``kinds/dense.py`` says what it provides)."""
+    kind = conf["model"]["kind"]
+    path = os.path.join(KINDS_DIR, kind + ".py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"configuration {conf.get('name')!r} has model.kind "
+                         f"{kind!r}, but {path} is missing")
+    return load_module(path, "bench_kind_" + kind.replace(".", "_"))
+
+
 def metric_reader(name: str):
     return load_module(os.path.join(BENCH_DIR, "metrics", name + ".py"),
                        "bench_metric_" + name.replace(".", "_")).read
@@ -86,26 +105,6 @@ class RunContext:
     flops_per_live_row: int = 0
     tree_work: object = None
     extra: dict = field(default_factory=dict)
-
-
-def check_program_config(cfg, conf: dict) -> None:
-    """The program's registered configuration has to be the file's."""
-    m, d = conf["model"], conf["draft"]
-    want = {"n_layers": m["n_layers"], "d_model": m["d_model"],
-            "n_heads": m["n_heads"], "n_kv_heads": m["n_kv_heads"],
-            "resolved_head_dim": m["head_dim"], "d_ff": m["d_ff"],
-            "vocab_size": m["vocab_size"], "rope_theta": m["rope_theta"],
-            "rms_eps": m["rms_eps"], "tie_embeddings": m["tie_embeddings"],
-            "dtype": conf["dtype"]}
-    for k, v in want.items():
-        if getattr(cfg, k) != v:
-            raise SystemExit(f"program config {cfg.name}.{k}="
-                             f"{getattr(cfg, k)!r}, file says {v!r}")
-    dc = cfg.draft
-    got = (dc.kind, dc.n_heads, dc.n_mlp_layers, dc.prefix_attention)
-    if got != (d["kind"], d["n_heads"], d["n_mlp_layers"],
-               d["prefix_attention"]):
-        raise SystemExit(f"program draft {got} != file {d}")
 
 
 def _ms(t, due, close):
@@ -139,9 +138,10 @@ def run(cell: dict, conf: dict, mix: dict, *, seed: int, seconds: float,
         after_check=None, log=print):
     """One run; returns the result object (the last line's contents) and
     a dict of extras: the run's ``RunContext`` (``ctx``), and what
-    ``after_check(params, model, picked, pad_to)`` returned (the control's
-    reading, read on the same requests while the weights are still
-    there)."""
+    ``after_check(kind, params, model, picked, pad_to)`` returned (the
+    control's reading, read on the same requests while the weights are
+    still there; ``kind`` is the configuration's ``kind_module``)."""
+    kind = kind_module(conf)
     import jax
     import jax.numpy as jnp
 
@@ -172,7 +172,7 @@ def run(cell: dict, conf: dict, mix: dict, *, seed: int, seconds: float,
         f"x{len(devices)} jax={jax.__version__} cache={cache_dir}")
 
     cfg = cfg or get_config(conf["program_config"])
-    check_program_config(cfg, conf)
+    kind.check_program(cfg, conf)
     e = mix["engine"]
     vocab = conf["model"]["vocab_size"]
 
@@ -229,17 +229,15 @@ def run(cell: dict, conf: dict, mix: dict, *, seed: int, seconds: float,
         f"{window_compiles}, engine counters "
         f"{ {k: win.stats_close[k] - win.stats_open[k] for k in win.stats_close} }")
 
-    from work.tree_attn import OP_PATTERN, work as tree_work
-    from work.verify_step import flops_per_live_row
+    from work.tree_attn import OP_PATTERN
     m, d = conf["model"], conf["draft"]
     T = sum(d["tree_nodes_per_depth"])
     T_pad = -(-T // 8) * 8
     ctx = RunContext(
         win=win, seconds=seconds, peaks=peaks, model=m, draft=d,
         kernel_pattern=OP_PATTERN,
-        flops_per_live_row=flops_per_live_row(m, d),
-        tree_work=lambda cached: tree_work(
-            cached, T_pad, m["n_heads"], m["n_kv_heads"], m["head_dim"]))
+        flops_per_live_row=kind.flops_per_live_row(m, d),
+        tree_work=lambda cached: kind.tree_work(cached, T_pad, m))
     breakdown = None
     if trace:
         t = time.time()
@@ -277,7 +275,7 @@ def run(cell: dict, conf: dict, mix: dict, *, seed: int, seconds: float,
                           [r for r in recs if not r.req.done])
     pad_to = -(-traffic.longest_context(mix) // 256) * 256
     t = time.time()
-    got = check.served_gap(params, m, picked, pad_to)
+    got = check.served_gap(kind, params, m, picked, pad_to)
     limit = float(mix["check"]["gap_limit"])
     log(f"[bench] check: {len(picked)} requests ({len(done)} finished), "
         f"{got['tokens']} served tokens, per-request widest gaps "
@@ -289,7 +287,8 @@ def run(cell: dict, conf: dict, mix: dict, *, seed: int, seconds: float,
         "window_compiles": {"value": window_compiles, "limit": 0},
     }
     if after_check is not None:
-        ctx.extra["after_check"] = after_check(params, m, picked, pad_to)
+        ctx.extra["after_check"] = after_check(kind, params, m, picked,
+                                               pad_to)
     correct = check.decide(checks)
     for b in bad[:5]:
         log(f"[bench] invalid: {b}")

@@ -14,6 +14,9 @@ against the cell's limit.
 The control (``control_gap``) puts the reference computed through fp8
 weights in the program's place: at the same positions it reads the gap
 of the token the fp8 reference puts first.
+
+The reference is the ``logits_at`` of the configuration's kind module
+(``kind``, from ``cell.kind_module``).
 """
 from __future__ import annotations
 
@@ -21,7 +24,6 @@ from typing import List
 
 import numpy as np
 
-from harness import reference
 from harness.traffic import rng_for
 
 
@@ -93,13 +95,14 @@ def _sequence(r):
     return tokens, positions, out
 
 
-def served_gap(params, model: dict, records: list, pad_to: int) -> dict:
+def served_gap(kind, params, model: dict, records: list, pad_to: int
+               ) -> dict:
     """Widest reference-logit gap of the served tokens over ``records``."""
     worst, n_tok, per = 0.0, 0, []
     for r in records:
         tokens, pos, out = _sequence(r)
-        ref = np.asarray(reference.logits_at(params, model, tokens, pos,
-                                             pad_to=pad_to))
+        ref = np.asarray(kind.logits_at(params, model, tokens, pos,
+                                        pad_to=pad_to))
         gap = ref.max(-1) - ref[np.arange(len(out)), out]
         per.append(float(gap.max()))
         worst = max(worst, float(gap.max()))
@@ -107,16 +110,17 @@ def served_gap(params, model: dict, records: list, pad_to: int) -> dict:
     return {"gap": worst, "tokens": n_tok, "per_request": per}
 
 
-def control_gap(params, model: dict, records: list, pad_to: int) -> dict:
+def control_gap(kind, params, model: dict, records: list, pad_to: int
+                ) -> dict:
     """The control's reading on the same prompts and served tokens: the
     reference gap of the token the fp8 reference puts first."""
     worst, n_tok, flips = 0.0, 0, 0
     for r in records:
         tokens, pos, out = _sequence(r)
-        ref = np.asarray(reference.logits_at(params, model, tokens, pos,
-                                             pad_to=pad_to))
-        low = np.asarray(reference.logits_at(params, model, tokens, pos,
-                                             quant="fp8", pad_to=pad_to))
+        ref = np.asarray(kind.logits_at(params, model, tokens, pos,
+                                        pad_to=pad_to))
+        low = np.asarray(kind.logits_at(params, model, tokens, pos,
+                                        quant="fp8", pad_to=pad_to))
         top = low.argmax(-1)
         gap = ref.max(-1) - ref[np.arange(len(out)), top]
         flips += int((top != ref.argmax(-1)).sum())

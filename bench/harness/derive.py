@@ -152,30 +152,32 @@ def step_mfu(ctx) -> Optional[float]:
     return 100.0 * flops / (secs * ctx.peaks["bf16_flops"])
 
 
-def certain_rows(ctx) -> List[int]:
-    """Prompt lengths of the requests certainly live in every step of the
-    traced window: first token before it opened, not done before it
-    closed.  A preemption in the window evicts the most recently joined
-    row, so for each one the latest-joined of these is dropped."""
-    t = ctx.win.trace
-    live = [r.req for r in ctx.win.records
-            if r.req.t_first_token is not None
-            and r.req.t_first_token < t["t_open"]
-            and (r.req.t_done is None or r.req.t_done > t["t_close"])]
-    live.sort(key=lambda q: q.t_join)
-    evicted = t["stats_close"]["preemptions"] - t["stats_open"]["preemptions"]
-    return [len(q.prompt) for q in live[:max(len(live) - evicted, 0)]]
-
-
 def tree_attn_roofline(ctx) -> Optional[float]:
-    """Least time of the kernel's certain work over its device time."""
-    cached = certain_rows(ctx)
+    """Least time of the paged tree kernel's mean call over its device
+    time, in %, for the work the engine counted in the traced window.
+
+    The calls are the kernel's in the window's verify-step runs.  The mean
+    step's work is the window's: the delta of ``kv_tokens_attended``
+    (each live row's prompt + output - 1 when its step ran) cached tokens
+    over the delta of ``active_slot_steps`` live row-steps, in the delta
+    of ``steps`` steps; ``ctx.tree_work`` (the kind's ``tree_work``)
+    gives one call's (flops, bytes) for it, and reads only the sum and
+    the count of the rows it is given.  The least time is
+    max(flops / peak, bytes / bandwidth).  None where the engine keeps no
+    ``kv_tokens_attended`` or the window ran no call."""
+    t = ctx.win.trace
+    if "kv_tokens_attended" not in t.get("stats_close", {}):
+        return None
+    d = {k: t["stats_close"][k] - t["stats_open"][k]
+         for k in ("kv_tokens_attended", "active_slot_steps", "steps")}
     calls = [o for m in _verify_runs(ctx)
              for o in tr.op_events(m, ctx.kernel_pattern)]
-    if not cached or not calls:
+    calls_ns = sum(e - s for _, s, e in calls)
+    rows, steps = d["active_slot_steps"], d["steps"]
+    if not (calls_ns and rows and steps):
         return None
-    flops, nbytes = ctx.tree_work(cached)
+    flops, nbytes = ctx.tree_work([d["kv_tokens_attended"]]
+                                  + [0] * (rows - 1))
     least = max(flops / ctx.peaks["bf16_flops"],
-                nbytes / ctx.peaks["hbm_bytes_per_s"])
-    secs = 1e-9 * sum(e - s for _, s, e in calls)
-    return 100.0 * len(calls) * least / secs
+                nbytes / ctx.peaks["hbm_bytes_per_s"]) / steps
+    return 100.0 * len(calls) * least / (1e-9 * calls_ns)

@@ -49,11 +49,14 @@ class Window:
 
 
 STAT_KEYS = ("steps", "tokens", "active_slot_steps", "capacity_slot_steps",
-             "preemptions", "prefill_chunks", "prefill_tokens")
+             "preemptions", "prefill_chunks", "prefill_tokens",
+             "kv_tokens_attended")
 
 
 def stats_snapshot(stats) -> dict:
-    return {k: getattr(stats, k) for k in STAT_KEYS}
+    """The engine's counters of ``STAT_KEYS``; one the engine does not
+    keep is left out (absent, never 0), and its readings give None."""
+    return {k: getattr(stats, k) for k in STAT_KEYS if hasattr(stats, k)}
 
 
 class Source:

@@ -16,8 +16,9 @@ run does not read these yet (PERF.md §7 names the edits that wire them).
                    text (the trace's op events carry the instruction only)
     phase_ns       device time of one program run per phase scope
     draft_ms       the draft heads' device time per verify-step run
-    live_roofline  ``tree_attn_roofline``'s formula over the work the
-                   engine counted: ``kv_tokens_attended`` and live row-steps
+
+``tree_attn_roofline`` (``harness.derive``) reads the engine's
+``kv_tokens_attended`` counter.
 """
 from __future__ import annotations
 
@@ -112,21 +113,3 @@ def draft_ms(runs: List[tr.Module], scopes: Dict[str, str]
     ns = sum(phase_ns(m, scopes).get(p, 0) for m in runs
              for p in DRAFT_PHASES)
     return 1e-6 * ns / len(runs)
-
-
-def live_roofline(calls_ns: int, n_calls: int, kv_tokens: int,
-                  row_steps: int, steps: int, work, peaks: dict
-                  ) -> Optional[float]:
-    """Least time of the kernel's mean call over its device time, in %.
-
-    The mean step's work is the window's: ``kv_tokens`` cached tokens over
-    ``row_steps`` live row-steps in ``steps`` steps (the deltas of
-    ``kv_tokens_attended``, ``active_slot_steps`` and ``steps``).
-    ``work(cached)`` is ``work.tree_attn.work`` at the cell's shapes; it
-    reads only the sum and the count of ``cached``."""
-    if not (calls_ns and n_calls and row_steps and steps):
-        return None
-    flops, nbytes = work([kv_tokens] + [0] * (row_steps - 1))
-    least = max(flops / peaks["bf16_flops"],
-                nbytes / peaks["hbm_bytes_per_s"]) / steps
-    return 100.0 * n_calls * least / (1e-9 * calls_ns)

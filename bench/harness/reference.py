@@ -1,4 +1,5 @@
-"""Plain reference models, in float32 at ``highest`` matmul precision.
+"""The plain reference of the ``dense`` kind (``kinds/dense.py``), in
+float32 at ``highest`` matmul precision.
 
 Written from the configuration file alone; nothing of the program is
 imported.  They read the same weight arrays the program serves (drawn by

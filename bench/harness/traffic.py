@@ -4,7 +4,12 @@ A mix file (``bench/traffic/<mix>.json``) holds only parameters:
 
     loop       "open": requests fall due on a schedule
     rate_rps   mean arrivals per second over the window
-    arrivals   "poisson"
+    arrivals   "poisson": spread over the whole window; or "onoff" with
+               ``on_s``, ``off_s`` and ``burst_factor``: the window opens
+               with an on-phase of ``on_s`` seconds, then ``off_s`` silent
+               ones, and so on; the arrivals are spread as for "poisson"
+               over the on-phases alone, so they come at ``burst_factor``
+               = (on_s + off_s) / on_s times the mean rate there
     schedule_seed
                draws the sizes and the arrival times: every run of the mix
                sends this one schedule, whatever its ``--seed``, which
@@ -28,11 +33,15 @@ to hold across the driver's fresh seeds.
 from __future__ import annotations
 
 import json
+import math
 from dataclasses import dataclass
 from statistics import NormalDist
 from typing import List
 
 import numpy as np
+
+ARRIVALS = ("poisson", "onoff")
+
 
 @dataclass(frozen=True)
 class Arrival:
@@ -46,6 +55,21 @@ def load_mix(path: str) -> dict:
         mix = json.load(f)
     if mix["loop"] != "open":
         raise ValueError(f"{path}: loop must be open")
+    kind = mix.get("arrivals", "poisson")
+    if kind not in ARRIVALS:
+        raise ValueError(f"{path}: arrivals {kind!r} is none of {ARRIVALS}")
+    if kind == "onoff":
+        missing = {"on_s", "off_s", "burst_factor"} - set(mix)
+        if missing:
+            raise ValueError(f"{path}: onoff arrivals need {sorted(missing)}")
+        on, off = float(mix["on_s"]), float(mix["off_s"])
+        if not (on > 0 and off >= 0):
+            raise ValueError(f"{path}: on_s must be > 0 and off_s >= 0")
+        if not math.isclose(mix["burst_factor"], (on + off) / on,
+                            rel_tol=1e-9):
+            raise ValueError(f"{path}: burst_factor {mix['burst_factor']} "
+                             f"is not (on_s + off_s) / on_s = "
+                             f"{(on + off) / on}")
     return mix
 
 
@@ -85,15 +109,26 @@ def _gaps(n: int, rng: np.random.Generator) -> np.ndarray:
 
 def open_schedule(mix: dict, seconds: float) -> List[Arrival]:
     """Every request due inside ``[0, seconds)``: ``round(rate * seconds)``
-    of them, the last one before the window closes."""
+    of them, the last one before the window closes (for "onoff", before
+    the last on-phase inside the window ends)."""
     n = max(int(round(mix["rate_rps"] * seconds)), 1)
     rng = rng_for(mix["schedule_seed"], 0)
     prompts = stratified_lengths(mix["prompt"], n, rng)
     outputs = stratified_lengths(mix["output"], n, rng)
     kind = mix.get("arrivals", "poisson")
-    if kind != "poisson":
+    if kind == "poisson":
+        due = np.cumsum(_gaps(n, rng)) * seconds * n / (n + 1)
+    elif kind == "onoff":
+        # spread over the on-phases' seconds alone, then each offset moved
+        # past the off-phases before it
+        on, period = mix["on_s"], mix["on_s"] + mix["off_s"]
+        whole, part = divmod(seconds, period)
+        t = np.cumsum(_gaps(n, rng)) * (whole * on + min(part, on)) * n / (
+            n + 1)
+        k = np.floor(t / on)
+        due = k * period + (t - k * on)
+    else:
         raise ValueError(f"unknown arrivals {kind!r}")
-    due = np.cumsum(_gaps(n, rng)) * seconds * n / (n + 1)
     return [Arrival(float(t), int(p), int(o))
             for t, p, o in zip(due, prompts, outputs)]
 

@@ -83,6 +83,17 @@ def test_config_file_is_its_own(bench):
             assert json.load(f)["name"] == c["name"]
 
 
+def test_every_kind_resolves(bench):
+    for c in bench["configs"]:
+        conf = cell.config_file(bench, c["name"])
+        kind = cell.kind_module(conf)
+        assert os.path.dirname(kind.__file__) == cell.KINDS_DIR
+        for name in ("check_program", "logits_at", "flops_per_live_row",
+                     "tree_work"):
+            assert callable(getattr(kind, name)), (conf["model"]["kind"],
+                                                   name)
+
+
 def test_run_exits_nonzero_without_a_tpu(bench):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     cell_name = bench["workloads"][0]["name"]

@@ -95,24 +95,6 @@ def test_op_base_and_top_ops():
     assert top == [("sort", 7), ("fusion", 5)]
 
 
-def test_certain_rows_drop_the_latest_joined_per_preemption():
-    req = lambda n, join, first, done: SimpleNamespace(
-        req=SimpleNamespace(prompt=[0] * n, t_join=join,
-                            t_first_token=first, t_done=done))
-    win = SimpleNamespace(records=[
-        req(10, 1.0, 2.0, None),      # live all through
-        req(20, 3.0, 4.0, 99.0),      # live all through, joined later
-        req(30, 1.5, 2.5, 6.0),       # finished inside the window
-        req(40, 5.5, 6.5, None),      # first token inside the window
-    ], trace={"t_open": 5.0, "t_close": 8.0,
-              "stats_open": {"preemptions": 0},
-              "stats_close": {"preemptions": 0}})
-    ctx = SimpleNamespace(win=win)
-    assert derive.certain_rows(ctx) == [10, 20]
-    win.trace["stats_close"]["preemptions"] = 1
-    assert derive.certain_rows(ctx) == [10]
-
-
 def test_idle_gaps_stay_inside_the_window():
     dt = tr.DeviceTrace(busy_ns=0, first_ns=0, last_ns=0, modules=[],
                         op_time={}, busy_intervals=[(0, 10), (20, 30),

@@ -10,7 +10,7 @@ from types import SimpleNamespace
 
 import pytest
 
-from harness import derive
+from harness import derive, driver
 from harness import engine_trace as et
 from harness import trace as tr
 from work.tree_attn import OP_PATTERN, work
@@ -154,26 +154,55 @@ def test_draft_ms(doc, chip):
     assert et.draft_ms([], doc["scopes"]) is None
 
 
+def _roofline_ctx(doc, chip, **stats):
+    t = dict(doc["trace"])
+    t["stats_close"] = dict(t["stats_close"], **stats)
+    return SimpleNamespace(
+        win=SimpleNamespace(trace=t), device=chip.dev,
+        kernel_pattern=OP_PATTERN, peaks=PEAKS,
+        tree_work=lambda cached: work(cached, 16, 24, 8, 128))
+
+
 def test_live_roofline_not_below_the_certain_bound(doc, chip):
+    live = derive.tree_attn_roofline(_roofline_ctx(doc, chip))
+    # the certain-work bound on the same calls: the prompts of the
+    # requests live over the whole traced window, each step
     t = doc["trace"]
-    d = {k: t["stats_close"][k] - t["stats_open"][k]
-         for k in ("kv_tokens_attended", "active_slot_steps", "steps")}
+    certain = [r["prompt"] for r in doc["requests"]
+               if r["first"] < t["t_open"]
+               and (r["done"] is None or r["done"] > t["t_close"])]
+    assert certain
     calls = [o for m in chip.verify for o in tr.op_events(m, OP_PATTERN)]
-    calls_ns = sum(e - s for _, s, e in calls)
-    shape = lambda cached: work(cached, 16, 24, 8, 128)
-    live = et.live_roofline(calls_ns, len(calls), d["kv_tokens_attended"],
-                            d["active_slot_steps"], d["steps"], shape, PEAKS)
-    # the accepted metric's lower bound, on the same calls
-    win = SimpleNamespace(trace=t, records=[SimpleNamespace(
-        req=SimpleNamespace(prompt=[0] * r["prompt"], t_join=r["join"],
-                            t_first_token=r["first"], t_done=r["done"]))
-        for r in doc["requests"]])
-    ctx = SimpleNamespace(win=win, device=chip.dev, kernel_pattern=OP_PATTERN,
-                          peaks=PEAKS, tree_work=shape)
-    bound = derive.tree_attn_roofline(ctx)
+    flops, nbytes = work(certain, 16, 24, 8, 128)
+    least = max(flops / PEAKS["bf16_flops"],
+                nbytes / PEAKS["hbm_bytes_per_s"])
+    bound = 100.0 * len(calls) * least / (1e-9 * sum(e - s for _, s, e
+                                                      in calls))
     assert 0 < bound <= live < 100
-    assert et.live_roofline(calls_ns, len(calls), 0, 0, 0, shape,
-                            PEAKS) is None
+
+
+def test_tree_attn_roofline_without_the_counter_or_the_work(doc, chip):
+    ctx = _roofline_ctx(doc, chip)
+    for snap in ("stats_open", "stats_close"):
+        ctx.win.trace[snap] = {k: v for k, v in ctx.win.trace[snap].items()
+                               if k != "kv_tokens_attended"}
+    assert derive.tree_attn_roofline(ctx) is None
+    # no step in the window, or no device trace: nothing to read
+    steps = doc["trace"]["stats_open"]["steps"]
+    assert derive.tree_attn_roofline(_roofline_ctx(doc, chip,
+                                                   steps=steps)) is None
+    ctx = _roofline_ctx(doc, chip)
+    ctx.device = None
+    assert derive.tree_attn_roofline(ctx) is None
+
+
+def test_stats_snapshot_leaves_out_a_counter_the_engine_lacks():
+    from repro.serving.engine import EngineStats
+    assert set(driver.stats_snapshot(EngineStats())) == set(driver.STAT_KEYS)
+    # an engine from before the counter: absent, never read as 0
+    old = SimpleNamespace(**{k: 1 for k in driver.STAT_KEYS
+                             if k != "kv_tokens_attended"})
+    assert "kv_tokens_attended" not in driver.stats_snapshot(old)
 
 
 def test_cpu_trace_of_a_paged_engine(tmp_path):

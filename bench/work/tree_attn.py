@@ -2,9 +2,8 @@
 
 One call serves one attention layer of one verify step: every tree query
 of a live row reads every cached K/V token of its row.  ``cached`` holds,
-per live row, the tokens certainly in that row's cache (the harness's own
-record: the prompt of a request that was live over the whole traced
-window).  Counted:
+per live row, the tokens in that row's cache (``harness.derive`` gives
+the engine's count, prompt + output - 1 when the step ran).  Counted:
 
     flops  4 * Hq * T * D per cached token (q.k and p.v); the tree's own
            T x T block is left out
