@@ -3,7 +3,8 @@
 Every architecture in the assigned pool is expressed as a ``ModelConfig``.
 Configs are plain frozen dataclasses so they hash (usable as jit static args)
 and print reproducibly.  ``reduced()`` returns the CPU smoke-test variant of
-the same family (<=2 layers, d_model<=512, <=4 experts).
+the same family (<=2 layers, d_model<=512, <=4 experts; a hybrid keeps two
+invocations of each shared block, so 5 layers for two blocks).
 """
 from __future__ import annotations
 
@@ -49,6 +50,7 @@ class SSMConfig:
     head_dim: int = 64            # SSD head dim
     conv_width: int = 4
     chunk_size: int = 64          # chunked-scan block length
+    n_groups: int = 1             # B/C groups: head i reads group i // (H / n_groups)
     # rwkv6 only
     rwkv_head_dim: int = 64
 
@@ -109,9 +111,15 @@ class ModelConfig:
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
 
-    # hybrid (zamba2): ssm backbone with a SHARED attention block invoked
-    # every `hybrid_attn_every` layers (weights reused, distinct KV cache slot)
-    hybrid_attn_every: int = 0
+    # hybrid (zamba2, models/model.py::group_program): a Mamba2 backbone in
+    # which each layer of `hybrid_layer_ids` is preceded by one invocation of
+    # a shared attention+MLP block over [h ‖ embedding].  The j-th such
+    # layer runs block j mod `num_mem_blocks` (weights shared between
+    # invocations) with a KV cache, a rank-`adapter_rank` LoRA on the MLP's
+    # gate/up projection and an output projection of its own.
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 1
+    adapter_rank: int = 0
 
     # block kinds per layer for ssm/hybrid: 'attn' | 'mamba2' | 'rwkv6'
     block_kind: str = "attn"
@@ -143,8 +151,6 @@ class ModelConfig:
         """True if the arch legally supports the 500k decode shape."""
         if self.block_kind in ("mamba2", "rwkv6"):
             return True
-        if self.hybrid_attn_every:
-            return True
         return any(w > 0 for w in self.window_pattern)
 
     @property
@@ -163,8 +169,14 @@ class ModelConfig:
         elif self.block_kind == "mamba2":
             s = self.ssm
             d_in = s.expand * d
-            per_layer = d * (2 * d_in + 2 * self.n_heads * 0 + 2 * s.d_state * 2) + d_in * d
-            per_layer += 2 * d * self.d_ff if self.d_ff else 0
+            per_layer = d * (2 * d_in + 2 * s.n_groups * s.d_state) + d_in * d
+            if self.hybrid_layer_ids:
+                hd = self.resolved_head_dim
+                block = (2 * d * 3 * self.n_heads * hd + self.n_heads * hd * d
+                         + 3 * d * self.d_ff)
+                inv = len(self.hybrid_layer_ids)
+                per_inv = (self.adapter_rank * (d + 2 * self.d_ff) + d * d)
+                per_layer += (self.num_mem_blocks * block + inv * per_inv) // L
         else:
             qkv = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd)
             o = self.n_heads * hd * d
@@ -204,7 +216,9 @@ class ModelConfig:
             d_model=min(self.d_model, 256),
             n_heads=min(self.n_heads, 4),
             n_kv_heads=min(self.n_kv_heads, 2),
-            head_dim=64,
+            # a head width off the 128 lanes stays off them: it decides the
+            # lane padding of the hybrid blocks' KV cache (models/attention.py)
+            head_dim=64 if self.resolved_head_dim % 64 == 0 else 96,
             d_ff=min(self.d_ff, 512),
             vocab_size=min(self.vocab_size, 512),
             max_seq_len=512,
@@ -224,8 +238,13 @@ class ModelConfig:
             )
         if self.ssm:
             kw["ssm"] = replace(self.ssm, d_state=16, chunk_size=16)
-        if self.hybrid_attn_every:
-            kw["hybrid_attn_every"] = 1
+        if self.hybrid_layer_ids:
+            # each shared block invoked twice; with several blocks a plain
+            # Mamba2 layer leads, as in the published layouts
+            inv = 2 * self.num_mem_blocks
+            n = inv + (self.num_mem_blocks > 1)
+            kw.update(n_layers=n, hybrid_layer_ids=tuple(range(n - inv, n)),
+                      adapter_rank=min(self.adapter_rank, 8))
         if len(self.window_pattern) > 1:
             kw["window_pattern"] = (64, 0)
         elif self.window_pattern != (0,):
@@ -281,7 +300,7 @@ def list_configs() -> list:
 
 
 ARCH_MODULES = [
-    "minitron_4b", "zamba2_1p2b", "hubert_xlarge", "qwen2p5_32b",
+    "minitron_4b", "zamba2_1p2b", "zamba2_7b", "hubert_xlarge", "qwen2p5_32b",
     "starcoder2_7b", "deepseek_v2_lite_16b", "deepseek_moe_16b",
     "rwkv6_1p6b", "chameleon_34b", "gemma3_1b", "vicuna_tiny",
 ]

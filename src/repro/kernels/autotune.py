@@ -212,7 +212,7 @@ def required_keys() -> list[tuple[str, int, int | None]]:
     keys = list(_SUITE_KEYS)
     for name in list_configs():
         cfg = get_config(name).reduced()
-        if cfg.block_kind != "attn" and not cfg.hybrid_attn_every:
+        if cfg.block_kind != "attn" and not cfg.hybrid_layer_ids:
             continue     # pure-SSM stacks never touch the attention paths
         windowed = any(w > 0 for w in cfg.window_pattern)
         for bs in (16,):                      # paged-engine test default
@@ -221,6 +221,8 @@ def required_keys() -> list[tuple[str, int, int | None]]:
                 keys.append(("mla_paged", hd, bs))
             else:
                 hd = cfg.resolved_head_dim
+                if cfg.hybrid_layer_ids:    # the pool's lane-padded width
+                    hd = -(-hd // 128) * 128
                 keys.append(("tree_paged", hd, bs))
                 if windowed:
                     keys.append(("tree_paged_windowed", hd, bs))

@@ -64,6 +64,7 @@ def tree_attention(q, cache_k, cache_v, tree_k, tree_v, tree_mask, cache_len,
 
 def tree_attention_paged(q, pool_k, pool_v, tree_k, tree_v, tree_mask,
                          cache_len, block_table, *, ppb: int | None = None,
+                         scale: float | None = None,
                          interpret: bool | None = None):
     """Tree verification streaming K/V from a paged block pool.
 
@@ -71,6 +72,7 @@ def tree_attention_paged(q, pool_k, pool_v, tree_k, tree_v, tree_mask,
     global head-major pool, NOT a per-slot view; tree_k/v: (B,Hkv,T,D);
     tree_mask: (T,T) bool ancestor-or-self; cache_len: (B,) int32
     committed length per slot; block_table: (B, M) int32 physical block ids (0 = NULL).
+    scale: the scores' scale, None => 1/sqrt(D).
     ppb: pages per compute block; None => the built-in default, about 128
     tokens (``paged_defaults``; ``tree_attention_paged_bshd`` resolves the
     autotuned winner).  ppb=1 updates page by page, as the per-entry grid
@@ -91,4 +93,4 @@ def tree_attention_paged(q, pool_k, pool_v, tree_k, tree_v, tree_mask,
         q, pool_k, pool_v, tree_k, tree_v, tree_mask, cache_len,
         block_table=block_table,
         spec=TemplateSpec(kind="tree", layout="paged"), ppb=ppb,
-        interpret=interpret)
+        scale=scale, interpret=interpret)

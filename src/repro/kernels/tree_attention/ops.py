@@ -64,12 +64,14 @@ def tree_attention_paged_bshd(q, pool_k, pool_v, tree_k, tree_v, tree_mask,
                               cache_len, block_table, *,
                               pad_to: int | None = None,
                               ppb: int | None = None,
+                              scale: float | None = None,
                               interpret: bool | None = None):
     """q/tree k,v: (B,T,H*,D) model layout; pool_k/v: the head-major
     global pool (num_blocks, Hkv, block_size, D) — streamed in place,
     never gathered;
     block_table: (B, M) int32.  pad_to/ppb: None => autotuned winners
-    (``paged_defaults`` without one).  Returns (B,T,Hq,D)."""
+    (``paged_defaults`` without one).  scale: None => 1/sqrt(D).
+    Returns (B,T,Hq,D)."""
     if pad_to is None or ppb is None:
         bs = pool_k.shape[2]
         tuned = tuned_block_sizes("tree_paged", q.shape[-1], block_size=bs,
@@ -82,5 +84,5 @@ def tree_attention_paged_bshd(q, pool_k, pool_v, tree_k, tree_v, tree_mask,
                              tree_k.transpose(0, 2, 1, 3),
                              tree_v.transpose(0, 2, 1, 3),
                              tree_mask, cache_len, block_table, ppb=ppb,
-                             interpret=interpret)
+                             scale=scale, interpret=interpret)
     return o.transpose(0, 2, 1, 3)[:, :T]

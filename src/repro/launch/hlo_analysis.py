@@ -120,8 +120,8 @@ def _cache_bytes_dev(cfg, shape, data_shards, model_shards) -> float:
         H = d_in // s.head_dim
         state = cfg.n_layers * B * H * s.d_state * s.head_dim * 4
         attn_tok = 0.0
-        if cfg.hybrid_attn_every:
-            n_inv = -(-cfg.n_layers // cfg.hybrid_attn_every)
+        if cfg.hybrid_layer_ids:
+            n_inv = len(cfg.hybrid_layer_ids)
             attn_tok = n_inv * 2 * cfg.n_kv_heads * cfg.resolved_head_dim * 2
         return (state + B * S * attn_tok) / data_shards
     if cfg.mla:

@@ -102,8 +102,22 @@ def init_gqa(key, cfg, dtype):
     return p
 
 
-def gqa_fwd(p, cfg, x, ai: AttnInputs):
-    """Returns (out (B,T,d), new_k (B,T,Hkv,D), new_v) — caller owns cache."""
+def lane_width(d: int) -> int:
+    """``d`` rounded up to whole 128-lane tiles: the head width a KV cache
+    stores when the kernel's page copy needs whole lane tiles."""
+    return -(-d // 128) * 128
+
+
+def gqa_fwd(p, cfg, x, ai: AttnInputs, *, scale: float | None = None,
+            kv_width: int | None = None):
+    """Returns (out (B,T,d), new_k (B,T,Hkv,D), new_v) — caller owns cache.
+
+    ``scale``: the scores' scale (None: 1/sqrt(head_dim)).  ``kv_width``:
+    the head width the cache stores, when wider than the head: q, k and v
+    are zero-padded to it after RoPE (zero columns leave every score as it
+    was; v's padded columns come out zero and are dropped), so every path
+    below runs on lane-aligned heads.  Mosaic copies no page of a head
+    width off the 128 lanes (``tests/test_tpu_compile.py``)."""
     B, T, _ = x.shape
     hd = cfg.resolved_head_dim
     q = x @ p["wq"]
@@ -118,22 +132,26 @@ def gqa_fwd(p, cfg, x, ai: AttnInputs):
     sin, cos = rope_sincos(ai.q_pos, hd, cfg.rope_theta)
     q = apply_rope(q, sin, cos)
     k = apply_rope(k, sin, cos)
+    if kv_width is not None and kv_width != hd:
+        pad = ((0, 0), (0, 0), (0, 0), (0, kv_width - hd))
+        q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
 
     if ai.cache_k is None:
         # full-sequence path (train / prefill): blocked flash attention
         kv_pos = ai.q_pos[0]  # assumes aligned positions across batch
         out = blocked_attention(q, k, v, ai.q_pos, kv_pos,
-                                window=ai.window, causal=ai.causal)
+                                window=ai.window, causal=ai.causal,
+                                scale=scale)
     elif ai.prefill:
         # chunked-prefill continuation: persist the chunk K/V at
         # [cache_len, cache_len+T), then run the SAME blocked attention
         # the whole-prompt prefill uses over the cache view — the masked
         # tail beyond cache_len+T is an exact online-softmax no-op, which
         # is what keeps chunked == unchunked byte-identical (§8)
-        out, k, v = _prefill_continuation(q, k, v, ai)
+        out, k, v = _prefill_continuation(q, k, v, ai, scale)
     elif ai.block_table is not None:
         # paged verify: scatter scratch through the table, stream the pool
-        out, k, v = _paged_verify_gqa(q, k, v, ai)
+        out, k, v = _paged_verify_gqa(q, k, v, ai, scale)
     else:
         # verify/decode path: write new kv into scratch region then attend
         S = ai.cache_k.shape[1]
@@ -142,9 +160,9 @@ def gqa_fwd(p, cfg, x, ai: AttnInputs):
         ck = ai.cache_k.at[bidx, slot].set(k.astype(ai.cache_k.dtype))
         cv = ai.cache_v.at[bidx, slot].set(v.astype(ai.cache_v.dtype))
         mask = _verify_mask(ai, B, T, S)
-        out = masked_attention(q, ck, cv, mask)
+        out = masked_attention(q, ck, cv, mask, scale)
         k, v = ck, cv  # return updated full cache
-    out = out.reshape(B, T, cfg.n_heads_padded * hd)
+    out = out[..., :hd].reshape(B, T, cfg.n_heads_padded * hd)
     return out @ p["wo"], k, v
 
 
@@ -174,7 +192,7 @@ def _cache_write(cache_k, cache_v, k, v, ai: AttnInputs):
     return ck, cv, ck, cv
 
 
-def _prefill_continuation(q, k, v, ai: AttnInputs):
+def _prefill_continuation(q, k, v, ai: AttnInputs, scale=None):
     """One chunk of a resumable prefill: write K/V, then full-seq blocked
     attention over the cache view.  Positions at or beyond
     ``cache_len + T`` (stale verify scratch, later chunks' zeros, NULL
@@ -186,7 +204,7 @@ def _prefill_continuation(q, k, v, ai: AttnInputs):
     S = k_view.shape[1]
     out = blocked_attention(q, k_view, v_view, ai.q_pos, jnp.arange(S),
                             window=ai.window, causal=ai.causal,
-                            kv_valid_len=ai.cache_len + T)
+                            kv_valid_len=ai.cache_len + T, scale=scale)
     return out, ck, cv
 
 
@@ -227,7 +245,7 @@ def _paged_gather_layer(pool, table):
     return view, covered
 
 
-def _paged_verify_gqa(q, k, v, ai: AttnInputs):
+def _paged_verify_gqa(q, k, v, ai: AttnInputs, scale=None):
     """Pool-layout verify for GQA: persist the T new K/V through the block
     table (token-granular scatter — the only writes of the step), then
     attend with the native paged template.  Groups with sliding-window
@@ -250,12 +268,12 @@ def _paged_verify_gqa(q, k, v, ai: AttnInputs):
                 jnp.asarray(ai.window, jnp.int32))
         else:
             out = tree_attention_paged_bshd(q, npk, npv, k, v, tm,
-                                            ai.cache_len, table)
+                                            ai.cache_len, table, scale=scale)
     else:
         ck, covered = _paged_gather_layer(npk, table)
         cv, _ = _paged_gather_layer(npv, table)
         mask = _verify_mask(ai, B, T, ck.shape[1]) & covered[:, None, :]
-        out = masked_attention(q, ck, cv, mask)
+        out = masked_attention(q, ck, cv, mask, scale)
     return out, npk, npv
 
 

@@ -7,18 +7,23 @@ HLO compact).  Group kinds:
   attn_stack   — pre-norm transformer layers (GQA or MLA; dense or MoE FFN)
   mamba_stack  — Mamba2 layers
   rwkv_stack   — RWKV6 layers (time-mix + channel-mix)
-  shared_attn  — zamba2's shared transformer block (weights shared across
-                 invocations; distinct KV-cache slot per invocation)
+  hybrid       — zamba2: one invocation of a shared attention+MLP block
+                 (``hybrid_block_fwd``; weights shared by the invocations
+                 of a block, a KV cache, LoRA adapter and output projection
+                 per invocation) followed by one Mamba2 layer whose input
+                 it adds to
 
 Execution modes:
   'full'   — train/prefill over the whole sequence (blocked attention /
              chunked ssm scan); optionally fills a cache (prefill)
   'verify' — T speculative tokens (tree or chain) against a populated cache;
-             SSM groups additionally return per-token candidate states so
-             acceptance can roll back (see serving/cache.py::commit_cache)
+             SSM groups additionally return per-token candidate states
+             (Mamba2: the chain's inputs, to replay) so acceptance can roll
+             back (see serving/cache.py::commit_cache)
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, NamedTuple, Optional
 
@@ -27,12 +32,13 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.attention import (AttnInputs, gqa_fwd, init_gqa, init_mla,
-                                    mla_fwd)
-from repro.models.layers import embed_init, init_mlp, mlp_fwd, rms_norm
+                                    lane_width, mla_fwd)
+from repro.models.layers import (dense_init, embed_init, init_mlp, mlp_fwd,
+                                 rms_norm)
 from repro.models.moe import init_moe, moe_fwd
 from repro.models.ssm import (_gather_last_valid, init_mamba2, init_rwkv6,
                               mamba2_fwd, mamba2_dims, rwkv6_chanmix,
-                              rwkv6_timemix)
+                              rwkv6_timemix, ssd_state_shape)
 
 
 class ModelOutputs(NamedTuple):
@@ -48,20 +54,21 @@ class ModelOutputs(NamedTuple):
 
 
 def group_program(cfg: ModelConfig):
-    """Returns a list of (kind, n_layers) describing the stack."""
+    """Returns a list of (kind, n_layers) describing the stack.  A hybrid
+    backbone runs its layers in order: each layer of ``hybrid_layer_ids``
+    is a ``hybrid`` group of its own, the runs between them
+    ``mamba_stack`` groups."""
     if cfg.block_kind == "rwkv6":
         return [("rwkv_stack", cfg.n_layers)]
     if cfg.block_kind == "mamba2":
         groups = []
-        every = cfg.hybrid_attn_every
-        if not every:
-            return [("mamba_stack", cfg.n_layers)]
-        done = 0
-        while done < cfg.n_layers:
-            seg = min(every, cfg.n_layers - done)
-            groups.append(("shared_attn", 1))
-            groups.append(("mamba_stack", seg))
-            done += seg
+        for layer in range(cfg.n_layers):
+            if layer in cfg.hybrid_layer_ids:
+                groups.append(("hybrid", 1))
+            elif groups and groups[-1][0] == "mamba_stack":
+                groups[-1] = ("mamba_stack", groups[-1][1] + 1)
+            else:
+                groups.append(("mamba_stack", 1))
         return groups
     if cfg.moe:
         nd = cfg.moe.n_dense_layers
@@ -71,6 +78,19 @@ def group_program(cfg: ModelConfig):
         out.append(("attn_stack_moe", cfg.n_layers - nd))
         return out
     return [("attn_stack_dense", cfg.n_layers)]
+
+
+def hybrid_scale(cfg: ModelConfig) -> float:
+    """The shared blocks' score scale, (head_dim / 2) ** -1/2, as
+    transformers' ``modeling_zamba2.py`` computes it."""
+    return (cfg.resolved_head_dim / 2) ** -0.5
+
+
+def hybrid_kv_width(cfg: ModelConfig) -> int:
+    """Head width of the shared blocks' KV cache: the head rounded up to
+    whole 128-lane tiles (zamba2-7b: 224 -> 256, 14% more pool bytes), as
+    the paged tree kernel copies pages of whole lane tiles only."""
+    return lane_width(cfg.resolved_head_dim)
 
 
 # ---------------------------------------------------------------------------
@@ -91,6 +111,38 @@ def _init_attn_layer(key, cfg, dtype, moe_ffn: bool):
     else:
         p["mlp"] = init_mlp(k2, cfg.d_model, cfg.d_ff, dtype)
     return p
+
+
+def _init_shared_block(key, cfg, dtype):
+    """One shared block: attention over [h ‖ embedding] (2d wide) into d,
+    then a gated MLP."""
+    d = cfg.d_model
+    k1, k2 = jax.random.split(key)
+    wide = dataclasses.replace(cfg, d_model=2 * d)
+    attn = init_gqa(k1, wide, dtype)
+    attn["wo"] = dense_init(jax.random.fold_in(k1, 1),
+                            cfg.n_heads * cfg.resolved_head_dim, d, dtype)
+    return {"norm_in": jnp.zeros((2 * d,), dtype), "attn": attn,
+            "norm_ff": jnp.zeros((d,), dtype),
+            "mlp": init_mlp(k2, d, cfg.d_ff, dtype)}
+
+
+def _init_mamba_layer(key, cfg, dtype):
+    return {"norm": jnp.zeros((cfg.d_model,), dtype),
+            "mamba": init_mamba2(key, cfg, dtype)}
+
+
+def _init_hybrid_group(key, cfg, dtype):
+    """A hybrid layer's own weights: the LoRA adapter of its invocation's
+    MLP gate/up (A_j, B_j), its output projection Lin_j, and its Mamba2
+    layer (a stack of one)."""
+    d, r = cfg.d_model, cfg.adapter_rank
+    ks = jax.random.split(key, 4)
+    return {"linear": dense_init(ks[0], d, d, dtype),
+            "lora_a": dense_init(ks[2], d, r, dtype),
+            "lora_b": dense_init(ks[3], r, 2 * cfg.d_ff, dtype),
+            "layers": _stack_init(lambda k: _init_mamba_layer(k, cfg, dtype),
+                                  ks[1], 1)}
 
 
 def _stack_init(fn, key, n):
@@ -119,7 +171,6 @@ def init_params(key, cfg: ModelConfig):
 
     groups = []
     prog = group_program(cfg)
-    shared_attn_params = None
     for gi, (kind, n) in enumerate(prog):
         gk = keys[4 + gi]
         if kind == "attn_stack_dense":
@@ -130,21 +181,19 @@ def init_params(key, cfg: ModelConfig):
                 lambda k: _init_attn_layer(k, cfg, dtype, moe_ffn=True), gk, n))
         elif kind == "mamba_stack":
             groups.append(_stack_init(
-                lambda k: {"norm": jnp.zeros((cfg.d_model,), dtype),
-                           "mamba": init_mamba2(k, cfg, dtype)}, gk, n))
+                lambda k: _init_mamba_layer(k, cfg, dtype), gk, n))
         elif kind == "rwkv_stack":
             groups.append(_stack_init(
                 lambda k: {"norm1": jnp.zeros((cfg.d_model,), dtype),
                            "norm2": jnp.zeros((cfg.d_model,), dtype),
                            "rwkv": init_rwkv6(k, cfg, dtype)}, gk, n))
-        elif kind == "shared_attn":
-            if shared_attn_params is None:
-                shared_attn_params = _init_attn_layer(keys[3], cfg, dtype,
-                                                      moe_ffn=False)
-            groups.append({})                      # weights live in shared slot
+        elif kind == "hybrid":
+            groups.append(_init_hybrid_group(gk, cfg, dtype))
     params["groups"] = groups
-    if shared_attn_params is not None:
-        params["shared_attn"] = shared_attn_params
+    if cfg.hybrid_layer_ids:
+        params["shared"] = [
+            _init_shared_block(jax.random.fold_in(keys[3], b), cfg, dtype)
+            for b in range(cfg.num_mem_blocks)]
     return params
 
 
@@ -174,20 +223,17 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                     "k": jnp.zeros((n, batch, max_len, cfg.n_kv_heads, hd), dtype),
                     "v": jnp.zeros((n, batch, max_len, cfg.n_kv_heads, hd), dtype),
                 })
-        elif kind == "shared_attn":
-            caches.append({
-                "k": jnp.zeros((1, batch, max_len, cfg.n_kv_heads, hd), dtype),
-                "v": jnp.zeros((1, batch, max_len, cfg.n_kv_heads, hd), dtype),
-            })
-        elif kind == "mamba_stack":
+        elif kind in ("mamba_stack", "hybrid"):
             s = cfg.ssm
-            d_in, H, conv_ch = mamba2_dims(cfg)
-            caches.append({
-                "ssd_state": jnp.zeros((n, batch, H, s.d_state, s.head_dim),
-                                       jnp.float32),
-                "conv_win": jnp.zeros((n, batch, s.conv_width - 1, conv_ch),
-                                      dtype),
-            })
+            conv_ch = mamba2_dims(cfg)[2]
+            c = {"ssd_state": jnp.zeros((n, batch, *ssd_state_shape(cfg)),
+                                        jnp.float32),
+                 "conv_win": jnp.zeros((n, batch, s.conv_width - 1, conv_ch),
+                                       dtype)}
+            if kind == "hybrid":
+                kv = (1, batch, max_len, cfg.n_kv_heads, hybrid_kv_width(cfg))
+                c.update(k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype))
+            caches.append(c)
         elif kind == "rwkv_stack":
             H = cfg.n_heads
             hd_r = cfg.d_model // H
@@ -244,6 +290,57 @@ def group_has_window(cfg: ModelConfig, offset: int, n: int) -> bool:
     (window rides as a traced scan operand; 0 is an exact mask no-op for
     the group's global layers)."""
     return any(cfg.window_for_layer(offset + i) > 0 for i in range(n))
+
+
+def hybrid_block_fwd(sp, gp, cfg, h, emb, ai: AttnInputs):
+    """One invocation of a shared block (Zamba2): returns (t, new_k,
+    new_v), t = Lin_j(MLP_j(RMSNorm(Attn(RMSNorm([h ‖ e]))))) where e is
+    the embedding, Attn runs the block's weights ``sp`` over the
+    invocation's own cache (``ai``) with scores scaled by
+    ``hybrid_scale``, and MLP_j is the block's GELU-gated MLP with the
+    invocation's LoRA adapter added to its gate/up projection:
+    [g ‖ u] = m W_gu + (m A_j) B_j.  t feeds the input of the
+    invocation's Mamba2 layer, not the residual stream."""
+    u = rms_norm(jnp.concatenate([h, emb], axis=-1), sp["norm_in"],
+                 cfg.rms_eps)
+    a, nk, nv = gqa_fwd(sp["attn"], cfg, u, ai, scale=hybrid_scale(cfg),
+                        kv_width=hybrid_kv_width(cfg))
+    m = rms_norm(a, sp["norm_ff"], cfg.rms_eps)
+    f = sp["mlp"]
+    lo = (m @ gp["lora_a"]) @ gp["lora_b"]
+    g = m @ f["w_gate"] + lo[..., :cfg.d_ff]
+    up = m @ f["w_up"] + lo[..., cfg.d_ff:]
+    g = jax.nn.gelu(g, approximate=False)         # the exact, erf form
+    return ((g * up) @ f["w_down"]) @ gp["linear"], nk, nv
+
+
+def _mamba_scan(layers, cfg, h, gc, n, B, *, is_verify, valid_len,
+                inject=None):
+    """Run a stack of ``n`` Mamba2 layers h += Mamba2(RMSNorm(x)) under a
+    ``lax.scan``, x = h (+ ``inject``, a hybrid block's output, for the
+    one layer of a hybrid group).  Returns (h, ssd_state, conv_win): the
+    final states (full mode) or the per-token candidates (verify)."""
+    mmode = "verify" if is_verify else "full"
+    vlen = None if is_verify else valid_len
+
+    def mbody(h, xs):
+        lp, ssd0, conv0 = xs
+        x = h if inject is None else h + inject
+        x2 = rms_norm(x, lp["norm"], cfg.rms_eps)
+        with jax.named_scope("ssm"):
+            y, ns = mamba2_fwd(lp["mamba"], cfg, x2, mode=mmode,
+                               ssd_state=ssd0, conv_state=conv0,
+                               valid_len=vlen)
+        return h + y, (ns["ssd_state"], ns["conv_win"])
+
+    ssd0 = gc["ssd_state"] if gc is not None else jnp.zeros(
+        (n, B, *ssd_state_shape(cfg)), jnp.float32)
+    conv0 = gc["conv_win"] if gc is not None else jnp.zeros(
+        (n, B, cfg.ssm.conv_width - 1, mamba2_dims(cfg)[2]),
+        jnp.dtype(cfg.dtype))
+    mbody_x = jax.checkpoint(mbody) if not is_verify else mbody
+    h, (nssd, nconv) = jax.lax.scan(mbody_x, h, (layers, ssd0, conv0))
+    return h, nssd, nconv
 
 
 # ---------------------------------------------------------------------------
@@ -304,7 +401,8 @@ def forward(params, cfg: ModelConfig, inputs, positions, *, mode: str = "full",
 
     prog = group_program(cfg)
     layer_offset = 0
-    shared_inv = 0
+    hybrid_inv = 0
+    emb = h                          # the hybrid blocks read [h ‖ embedding]
     for gi, (kind, n) in enumerate(prog):
         gp = params["groups"][gi]
         gc = cache[gi] if cache is not None else None
@@ -370,49 +468,38 @@ def forward(params, cfg: ModelConfig, inputs, positions, *, mode: str = "full",
                             "v": gc["v"].at[:, :, :T].set(
                                 nv.astype(gc["v"].dtype))})
 
-        elif kind == "shared_attn":
-            sp = params["shared_attn"]
-            win = jnp.int32(0)
-            if is_verify or is_chunk:
-                ai = AttnInputs(q_pos=positions, cache_k=gc["k"][0],
-                                cache_v=gc["v"][0], cache_len=cache_len,
-                                tree_mask=tree_mask if is_verify else None,
-                                window=win, causal=True,
-                                block_table=block_table, prefill=is_chunk)
-                h, nk, nv, _ = _attn_layer_fwd(sp, cfg, h, ai, moe_ffn=False)
-                new_cache.append({"k": nk[None], "v": nv[None]})
-            else:
-                ai = AttnInputs(q_pos=positions, cache_k=None, cache_v=None,
-                                cache_len=None, tree_mask=None, window=win,
-                                causal=True)
-                h, nk, nv, _ = _attn_layer_fwd(sp, cfg, h, ai, moe_ffn=False)
-                if cache is not None:
-                    new_cache.append({
-                        "k": gc["k"].at[:, :, :T].set(
-                            nk[None].astype(gc["k"].dtype)),
-                        "v": gc["v"].at[:, :, :T].set(
-                            nv[None].astype(gc["v"].dtype))})
-            shared_inv += 1
+        elif kind == "hybrid":
+            # the j-th invocation runs block j mod num_mem_blocks
+            sp = params["shared"][hybrid_inv % cfg.num_mem_blocks]
+            with jax.named_scope("shared_block"):
+                if is_verify or is_chunk:
+                    ai = AttnInputs(
+                        q_pos=positions, cache_k=gc["k"][0],
+                        cache_v=gc["v"][0], cache_len=cache_len,
+                        tree_mask=tree_mask if is_verify else None,
+                        window=jnp.int32(0), causal=True,
+                        block_table=block_table, prefill=is_chunk)
+                else:
+                    ai = AttnInputs(q_pos=positions, cache_k=None,
+                                    cache_v=None, cache_len=None,
+                                    tree_mask=None, window=jnp.int32(0),
+                                    causal=True)
+                t, nk, nv = hybrid_block_fwd(sp, gp, cfg, h, emb, ai)
+            h, nssd, nconv = _mamba_scan(gp["layers"], cfg, h, gc, 1, B,
+                                         is_verify=is_verify,
+                                         valid_len=valid_len, inject=t)
+            if cache is not None:
+                if not (is_verify or is_chunk):    # prefill: write [0, T)
+                    nk = gc["k"][0].at[:, :T].set(nk.astype(gc["k"].dtype))
+                    nv = gc["v"][0].at[:, :T].set(nv.astype(gc["v"].dtype))
+                new_cache.append({"ssd_state": nssd, "conv_win": nconv,
+                                  "k": nk[None], "v": nv[None]})
+            hybrid_inv += 1
 
         elif kind == "mamba_stack":
-            mmode = "verify" if is_verify else "full"
-            vlen = None if is_verify else valid_len
-
-            def mbody(h, xs):
-                lp, ssd0, conv0 = xs
-                x2 = rms_norm(h, lp["norm"], cfg.rms_eps)
-                y, ns = mamba2_fwd(lp["mamba"], cfg, x2, mode=mmode,
-                                   ssd_state=ssd0, conv_state=conv0,
-                                   valid_len=vlen)
-                return h + y, (ns["ssd_state"], ns["conv_win"])
-
-            ssd0 = gc["ssd_state"] if gc is not None else jnp.zeros(
-                (n, B, *init_cache_shapes_mamba(cfg)), jnp.float32)
-            conv0 = gc["conv_win"] if gc is not None else jnp.zeros(
-                (n, B, cfg.ssm.conv_width - 1, mamba2_dims(cfg)[2]),
-                jnp.dtype(cfg.dtype))
-            mbody_x = jax.checkpoint(mbody) if not is_verify else mbody
-            h, (nssd, nconv) = jax.lax.scan(mbody_x, h, (gp, ssd0, conv0))
+            h, nssd, nconv = _mamba_scan(gp, cfg, h, gc, n, B,
+                                         is_verify=is_verify,
+                                         valid_len=valid_len)
             if cache is not None:
                 new_cache.append({"ssd_state": nssd, "conv_win": nconv})
 
@@ -455,7 +542,7 @@ def forward(params, cfg: ModelConfig, inputs, positions, *, mode: str = "full",
                 new_cache.append({"wkv_state": nwkv, "shift_tm": nstm,
                                   "shift_cm": nscm})
 
-        layer_offset += n if kind != "shared_attn" else 0
+        layer_offset += n
 
     h = rms_norm(h, params["final_norm"], cfg.rms_eps)
     logits = None
@@ -465,9 +552,3 @@ def forward(params, cfg: ModelConfig, inputs, positions, *, mode: str = "full",
         logits = (h.astype(jnp.float32) @ unembed.astype(jnp.float32))
     return ModelOutputs(hidden=h, logits=logits, cache=new_cache,
                         aux_loss=aux_total)
-
-
-def init_cache_shapes_mamba(cfg):
-    s = cfg.ssm
-    _, H, _ = mamba2_dims(cfg)
-    return (H, s.d_state, s.head_dim)

@@ -10,12 +10,14 @@ Mamba2: per-token per-head scalar decay a_t = exp(dt_t * A_h); readout uses
 S_t, which maps onto the same primitive via r' = r * a_t and u = 1.
 
 Two execution paths share the math:
-  * ``decay_attention_chunked`` — train/prefill: chunked scan (intra-chunk
-    matmul + inter-chunk state recurrence).  Mirrored by the Pallas kernel
-    ``repro.kernels.linear_attn_chunk`` (TPU target).
-  * ``decay_attention_seq`` — decode/verify: per-token scan that RETURNS all
-    intermediate states so chain-speculative verification can roll back to
-    the last accepted token without recompute.
+  * ``decay_attention_chunked`` / ``mamba2_ssd_chunked`` — train/prefill:
+    chunked scan (intra-chunk matmul + inter-chunk state recurrence).
+    Mirrored by the Pallas kernel ``repro.kernels.linear_attn_chunk``.
+  * verify: RWKV6's ``decay_attention_seq`` RETURNS every per-token state
+    so chain-speculative verification can roll back to the last accepted
+    token without recompute; Mamba2's chain runs through the Pallas
+    kernels ``repro.kernels.ssd_chain``, whose commit replays the
+    accepted prefix instead (serving/cache.py).
 """
 from __future__ import annotations
 
@@ -140,13 +142,9 @@ def decay_attention_chunked(r, k, v, w_log, u=None, initial_state=None,
     return o.astype(v.dtype), state
 
 
-def decay_attention_seq(r, k, v, w_log, u=None, initial_state=None,
-                        readout: str = "pre"):
-    """Per-token scan; returns (o, states_per_token (B,T,H,dk,dv)).
-
-    readout='pre'  (RWKV6): o_t = r_t S_{t-1} + (r_t.(u*k_t)) v_t
-    readout='post' (Mamba2): o_t = r_t S_t  (state inclusive of token t)
-    """
+def decay_attention_seq(r, k, v, w_log, u=None, initial_state=None):
+    """Per-token scan (RWKV6 verify); returns (o, states_per_token
+    (B,T,H,dk,dv)), o_t = r_t S_{t-1} + (r_t.(u*k_t)) v_t."""
     B, T, H, dk = k.shape
     dv = v.shape[-1]
     if initial_state is None:
@@ -160,18 +158,13 @@ def decay_attention_seq(r, k, v, w_log, u=None, initial_state=None,
 
     def body(state, xs):
         rt, kt, vt, wt = xs                               # (B,H,d*)
-        if readout == "pre":
-            o = jnp.einsum("bhd,bhdv->bhv", rt, state)
-            if u is not None:
-                o = o + jnp.einsum(
-                    "bhd,bhd->bh", rt * u.astype(jnp.float32)[None],
-                    kt)[..., None] * vt
-            state = state * jnp.exp(wt)[..., None] + \
-                kt[..., None] * vt[:, :, None]
-        else:
-            state = state * jnp.exp(wt)[..., None] + \
-                kt[..., None] * vt[:, :, None]
-            o = jnp.einsum("bhd,bhdv->bhv", rt, state)
+        o = jnp.einsum("bhd,bhdv->bhv", rt, state)
+        if u is not None:
+            o = o + jnp.einsum(
+                "bhd,bhd->bh", rt * u.astype(jnp.float32)[None],
+                kt)[..., None] * vt
+        state = state * jnp.exp(wt)[..., None] + \
+            kt[..., None] * vt[:, :, None]
         return state, (o, state)
 
     _, (o, states) = jax.lax.scan(body, S0, (rf, kf, vf, wf))
@@ -183,57 +176,58 @@ def decay_attention_seq(r, k, v, w_log, u=None, initial_state=None,
 def mamba2_ssd_chunked(r, k, v, w_log, initial_state=None, chunk: int = 64):
     """Grouped SSD chunked scan (Mamba2 full/train path, §Perf iter 2).
 
-    Exploits Mamba2's structure: B (k) and C (r) are SHARED across heads
-    (one group), decay is a per-head scalar — so the (c, c) score matrix is
-    computed ONCE per group instead of per head, and k/r are never
-    broadcast-materialized across the head axis.
+    Exploits Mamba2's structure: B (k) and C (r) are SHARED by the heads
+    of a group (head i reads group i // (H / G)), decay is a per-head
+    scalar — so the (c, c) score matrix is computed ONCE per group instead
+    of per head, and k/r are never broadcast-materialized across heads.
 
-    r/k: (B, S, ds) group-shared; v: (B, S, H, hd); w_log: (B, S, H)
-    per-head scalar log-decay (<= 0).  Readout is o_t = C_t · h_t with
+    r/k: (B, S, G, ds); v: (B, S, H, hd); w_log: (B, S, H) per-head scalar
+    log-decay (<= 0).  Readout is o_t = C_t · h_t with
     h_t = a_t h_{t-1} + B_t v_t  (state INCLUSIVE of token t).
     Returns (o: (B, S, H, hd), final_state: (B, H, ds, hd)).
     """
-    B, S, ds = k.shape
+    B, S, G, ds = k.shape
     H, hd = v.shape[2], v.shape[3]
+    Hg = H // G
     S_orig = S
     if S % chunk:
         pad = chunk - S % chunk
-        r = jnp.pad(r, ((0, 0), (0, pad), (0, 0)))
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0)))
+        r = jnp.pad(r, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
         w_log = jnp.pad(w_log, ((0, 0), (0, pad), (0, 0)))
         S += pad
     nc = S // chunk
-    rf = r.astype(jnp.float32).reshape(B, nc, chunk, ds).swapaxes(0, 1)
-    kf = k.astype(jnp.float32).reshape(B, nc, chunk, ds).swapaxes(0, 1)
-    vf = v.astype(jnp.float32).reshape(B, nc, chunk, H, hd).swapaxes(0, 1)
-    wf = w_log.astype(jnp.float32).reshape(B, nc, chunk, H).swapaxes(0, 1)
+    rf = r.astype(jnp.float32).reshape(B, nc, chunk, G, ds).swapaxes(0, 1)
+    kf = k.astype(jnp.float32).reshape(B, nc, chunk, G, ds).swapaxes(0, 1)
+    vf = v.astype(jnp.float32).reshape(B, nc, chunk, G, Hg, hd).swapaxes(0, 1)
+    wf = w_log.astype(jnp.float32).reshape(B, nc, chunk, G, Hg).swapaxes(0, 1)
     if initial_state is None:
-        S0 = jnp.zeros((B, H, ds, hd), jnp.float32)
+        S0 = jnp.zeros((B, G, Hg, ds, hd), jnp.float32)
     else:
-        S0 = initial_state.astype(jnp.float32)
+        S0 = initial_state.astype(jnp.float32).reshape(B, G, Hg, ds, hd)
     tri = jnp.tril(jnp.ones((chunk, chunk), bool))        # INCLUSIVE diag
 
     def body(state, xs):
         rc, kc, vc, wc = xs
-        lcw = jnp.cumsum(wc, axis=1)                      # (B,c,H) inclusive
-        A0 = jnp.einsum("btd,bsd->bts", rc, kc)           # group-shared
-        E = jnp.exp(jnp.minimum(lcw[:, :, None] - lcw[:, None, :, :], 0.0))
-        E = jnp.where(tri[None, :, :, None], E, 0.0)      # (B,t,s,H)
-        o = jnp.einsum("bts,btsh,bshv->bthv", A0, E, vc)
+        lcw = jnp.cumsum(wc, axis=1)                      # (B,c,G,Hg) inclusive
+        A0 = jnp.einsum("btgd,bsgd->bgts", rc, kc)        # group-shared
+        E = jnp.exp(jnp.minimum(lcw[:, :, None] - lcw[:, None, :], 0.0))
+        E = jnp.where(tri[None, :, :, None, None], E, 0.0)  # (B,t,s,G,Hg)
+        o = jnp.einsum("bgts,btsgh,bsghv->btghv", A0, E, vc)
         # inter-chunk: o_t += exp(lcw_t) * (r_t . S0)
-        rS = jnp.einsum("btd,bhdv->bthv", rc, state)
+        rS = jnp.einsum("btgd,bghdv->btghv", rc, state)
         o = o + jnp.exp(lcw)[..., None] * rS
         # state update
-        lcw_c = lcw[:, -1:]                               # (B,1,H)
-        dec = jnp.exp(lcw_c - lcw)                        # (B,c,H)
+        lcw_c = lcw[:, -1:]                               # (B,1,G,Hg)
+        dec = jnp.exp(lcw_c - lcw)                        # (B,c,G,Hg)
         state = state * jnp.exp(lcw_c[:, 0])[..., None, None] + jnp.einsum(
-            "bsh,bsd,bshv->bhdv", dec, kc, vc)
+            "bsgh,bsgd,bsghv->bghdv", dec, kc, vc)
         return state, o
 
     state, o = jax.lax.scan(body, S0, (rf, kf, vf, wf))
     o = jnp.swapaxes(o, 0, 1).reshape(B, S, H, hd)[:, :S_orig]
-    return o.astype(v.dtype), state
+    return o.astype(v.dtype), state.reshape(B, H, ds, hd)
 
 
 # ---------------------------------------------------------------------------
@@ -245,8 +239,17 @@ def mamba2_dims(cfg):
     s = cfg.ssm
     d_in = s.expand * cfg.d_model
     n_heads = d_in // s.head_dim
-    conv_ch = d_in + 2 * s.d_state
+    conv_ch = d_in + 2 * s.n_groups * s.d_state
     return d_in, n_heads, conv_ch
+
+
+def ssd_state_shape(cfg):
+    """One row's SSD state: ``(d_state, H * head_dim)`` — the state row of
+    every head side by side along the lanes, so that no axis of it falls
+    short of the TPU's 128 lanes (``kernels/ssd_chain``)."""
+    s = cfg.ssm
+    _, H, _ = mamba2_dims(cfg)
+    return (s.d_state, H * s.head_dim)
 
 
 def init_mamba2(key, cfg, dtype):
@@ -255,7 +258,7 @@ def init_mamba2(key, cfg, dtype):
     d_in, H, conv_ch = mamba2_dims(cfg)
     ks = jax.random.split(key, 4)
     return {
-        "w_in": dense_init(ks[0], d, 2 * d_in + 2 * s.d_state + H, dtype),
+        "w_in": dense_init(ks[0], d, d_in + conv_ch + H, dtype),
         "conv_w": (jax.random.normal(ks[1], (s.conv_width, conv_ch)) * 0.1
                    ).astype(dtype),
         "conv_b": jnp.zeros((conv_ch,), dtype),
@@ -291,8 +294,15 @@ def mamba2_fwd(p, cfg, x, *, mode: str, ssd_state=None, conv_state=None,
     """mode: 'full' (train/prefill, chunked) | 'verify' (per-token states).
 
     Returns (out, new_states) where new_states =
-      full:   {'ssd_state': (B,H,dk,dv) final, 'conv_win': (B,W-1,C) final}
-      verify: {'ssd_state': (B,T,H,dk,dv), 'conv_win': (B,T,W-1,C)} per token
+      full:   {'ssd_state': (B,ds,H*hd) final, 'conv_win': (B,W-1,C) final}
+      verify: {'ssd_state': the chain's inputs (a dict, per token), which
+               the commit replays from the state (serving/cache.py),
+               'conv_win': (B,T,W-1,C) per token}
+
+    The SSD state is kept in the ``ssd_state_shape`` layout.  B and C come
+    in ``n_groups`` groups (head i reads group i // (H / n_groups)), and
+    the gated RMSNorm is taken per group.  Verify runs the chain through
+    ``kernels/ssd_chain``.
 
     ``valid_len`` (B,), full mode only: right-pad positions >= valid_len
     are length-masked out of the scan (decay 1, key 0 — state carried past
@@ -303,7 +313,7 @@ def mamba2_fwd(p, cfg, x, *, mode: str, ssd_state=None, conv_state=None,
     s = cfg.ssm
     d_in, H, conv_ch = mamba2_dims(cfg)
     B, T, _ = x.shape
-    hd, ds = s.head_dim, s.d_state
+    hd, ds, G = s.head_dim, s.d_state, s.n_groups
 
     zxbcdt = x @ p["w_in"]
     z = zxbcdt[..., :d_in]
@@ -313,40 +323,39 @@ def mamba2_fwd(p, cfg, x, *, mode: str, ssd_state=None, conv_state=None,
     xbc, conv_windows = _causal_conv(xbc, p["conv_w"], p["conv_b"], conv_state)
     xbc = jax.nn.silu(xbc)
     xs = xbc[..., :d_in].reshape(B, T, H, hd)
-    Bmat = xbc[..., d_in:d_in + ds]                       # (B,T,ds) group=1
-    Cmat = xbc[..., d_in + ds:]
+    Bmat = xbc[..., d_in:d_in + G * ds].reshape(B, T, G, ds)
+    Cmat = xbc[..., d_in + G * ds:].reshape(B, T, G, ds)
 
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["a_log"])                              # (H,) negative
-    w_scalar = dt * A                                     # (B,T,H) <= 0
-    v = xs.astype(jnp.float32) * dt[..., None]            # (B,T,H,hd)
 
     if mode == "full":
-        # grouped SSD: B/C shared across heads — never broadcast (perf)
+        # grouped SSD: B/C shared by a group's heads — never broadcast
+        v = xs.astype(jnp.float32) * dt[..., None]        # (B,T,H,hd)
         w_m, B_m = _mask_decay_inputs(_pad_mask(valid_len, B, T),
-                                      w_scalar, Bmat.astype(jnp.float32))
+                                      dt * A, Bmat.astype(jnp.float32))
+        s0 = None
+        if ssd_state is not None:
+            s0 = ssd_state.reshape(B, ds, H, hd).transpose(0, 2, 1, 3)
         o, final_state = mamba2_ssd_chunked(
             Cmat.astype(jnp.float32), B_m, v, w_m,
-            initial_state=ssd_state, chunk=chunk or s.chunk_size)
-        new_states = {"ssd_state": final_state,
+            initial_state=s0, chunk=chunk or s.chunk_size)
+        new_states = {"ssd_state": final_state.transpose(0, 2, 1, 3).reshape(
+                          B, ds, H * hd),
                       "conv_win": _gather_last_valid(conv_windows,
                                                      valid_len)[:, 0]}
     else:
-        # per-token scan (T small): post-update readout o_t = C_t . h_t
-        w_log = w_scalar[..., None]                       # (B,T,H,1)
-        k = jnp.broadcast_to(Bmat[:, :, None, :],
-                             (B, T, H, ds)).astype(jnp.float32)
-        r = jnp.broadcast_to(Cmat[:, :, None, :],
-                             (B, T, H, ds)).astype(jnp.float32)
-        o, states = decay_attention_seq(r, k, v, w_log,
-                                        initial_state=ssd_state,
-                                        readout="post")
-        new_states = {"ssd_state": states, "conv_win": conv_windows}
+        from repro.kernels.ssd_chain.ops import ssd_chain_verify_bthd
+        if ssd_state is None:
+            ssd_state = jnp.zeros((B, ds, H * hd), jnp.float32)
+        o, chain = ssd_chain_verify_bthd(Cmat, Bmat, xs, dt, A, ssd_state)
+        new_states = {"ssd_state": chain, "conv_win": conv_windows}
 
     y = o.astype(jnp.float32) + p["d_skip"][None, None, :, None] * xs.astype(jnp.float32)
-    y = y.reshape(B, T, d_in).astype(x.dtype)
-    y = rms_norm(y * jax.nn.silu(z), p["norm"], cfg.rms_eps)
-    return y @ p["w_out"], new_states
+    y = y.reshape(B, T, d_in).astype(x.dtype) * jax.nn.silu(z)
+    y = rms_norm(y.reshape(B, T, G, d_in // G),
+                 p["norm"].reshape(G, d_in // G), cfg.rms_eps)
+    return y.reshape(B, T, d_in) @ p["w_out"], new_states
 
 
 # ---------------------------------------------------------------------------

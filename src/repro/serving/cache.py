@@ -5,12 +5,16 @@ After a verify forward pass the per-group caches hold *candidates*:
   attention groups ('k'/'v'): the cache arrays with all T tree tokens
     written in the scratch region [len, len+T); commit compacts the accepted
     root-path entries to [len, len+n_accept+1).
-  state groups ('ssd_state'/'conv_win'/'wkv_state'/'shift_*'): stacked
-    per-token candidate states on a T axis; commit selects the state of the
-    last accepted node.
+  state groups ('conv_win'/'wkv_state'/'shift_*'): stacked per-token
+    candidate states on a T axis; commit selects the state of the last
+    accepted node.  Mamba2's 'ssd_state' comes back as the chain's inputs
+    instead (``kernels/ssd_chain``): commit replays the accepted prefix from
+    the pre-verify state, which keeps T candidate states of every layer
+    out of memory.
 
-Both rules are pure gathers — no recompute — which is what makes chain
-speculation on SSM/hybrid architectures cheap (DESIGN.md §4).
+The attention rule and the selection are pure gathers, and the replay
+reads each state once — which is what makes chain speculation on
+SSM/hybrid architectures cheap (DESIGN.md §4).
 
 Commit is part of the traced step and must stay that way: nothing here
 may read a device value back to the host (no ``int()``/``bool()`` on
@@ -31,6 +35,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.ssd_chain.ops import ssd_chain_commit_rows
 
 ATTN_KEYS = {"k", "v"}
 
@@ -129,6 +135,14 @@ def commit_cache(candidates, cache_len, path_nodes, n_accept, *,
                 g[key] = _commit_attn(arr, cache_len, path_nodes,
                                       has_layer_axis=True,
                                       block_table=block_table)
+            elif isinstance(arr, dict):        # a Mamba2 chain to replay
+                if prev is None:    # trace-time check, never a host sync
+                    raise ValueError("committing a Mamba2 chain needs prev "
+                                     "(the pre-verify committed cache)")
+                keep = last_node + 1
+                if active is not None:
+                    keep = jnp.where(active, keep, 0)
+                g[key] = ssd_chain_commit_rows(arr, prev[gi][key], keep)
             else:
                 new = _commit_state(arr, last_node)
                 if active is not None:

@@ -503,7 +503,7 @@ class SpeculativeEngine(_EngineBase):
         # sequence-axis cache at all — one full-extent trace suffices
         from repro.models.model import group_program
         self._view_grows = (
-            any(k.startswith("attn") or k == "shared_attn"
+            any(k.startswith("attn") or k == "hybrid"
                 for k, _ in group_program(cfg))
             or (draft_params is not None and "prefix" in draft_params))
         greedy = self.criterion == "greedy"
