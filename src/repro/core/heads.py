@@ -146,6 +146,50 @@ def head_logits(dp, cfg: ModelConfig, base_params, i: int, h, path_embs):
 # ---------------------------------------------------------------------------
 
 
+def _total_order_key(x):
+    """float32 -> int32 whose signed order is IEEE-754 totalOrder, the order
+    ``lax.top_k`` ranks by (-0.0 below +0.0, NaNs at the ends); an
+    involution, so it also maps a key back to the float's bits."""
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(b < 0, b ^ np.int32(0x7FFFFFFF), b)
+
+
+def _argmax_key(key, idx):
+    """Largest key over the last axis, the lowest index among equals: one
+    reduction of (key, index) pairs.  Returns (key, index), each (...,)."""
+    def better(a, b):
+        take_a = (a[0] > b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
+        return (jnp.where(take_a, a[0], b[0]), jnp.where(take_a, a[1], b[1]))
+    init = (jnp.int32(np.iinfo(np.int32).min), jnp.int32(key.shape[-1]))
+    return jax.lax.reduce((key, idx), init, better, (key.ndim - 1,))
+
+
+def top_k_at_ranks(x, ranks):
+    """``lax.top_k(x, k)`` over the last axis, read at one rank per
+    position of the axis before it, without sorting.
+
+    x: (..., n, V) float32; ranks: (n,) static ints, k = max(ranks) + 1.
+    Returns (values, indices), each (..., n): exactly what ``top_k`` puts at
+    those ranks, ties ranked by lower index.  Runs k rounds of max-and-mask,
+    each one pass over x: a round takes the largest remaining entry and
+    retires its index; retired entries lose every comparison, so even
+    -inf or NaN entries are ranked as ``top_k`` ranks them."""
+    V = x.shape[-1]
+    key = _total_order_key(x)
+    iota = jax.lax.broadcasted_iota(jnp.int32, key.shape, key.ndim - 1)
+    retired = jnp.zeros(key.shape, jnp.bool_)
+    keys, idxs = [], []                   # round j: rank j of every position
+    for _ in range(int(np.max(ranks)) + 1):
+        k, i = _argmax_key(jnp.where(retired, np.iinfo(np.int32).min, key),
+                           jnp.where(retired, V, iota))
+        keys.append(k)
+        idxs.append(i)
+        retired = retired | (iota == i[..., None])
+    k = jnp.stack([keys[r][..., j] for j, r in enumerate(ranks)], -1)
+    i = jnp.stack([idxs[r][..., j] for j, r in enumerate(ranks)], -1)
+    return jax.lax.bitcast_convert_type(_total_order_key(k), jnp.float32), i
+
+
 def draft_tree_tokens(dp, cfg: ModelConfig, base_params, tree, h, last_tok):
     """Populate the candidate tree (paper §2 'tree decoding' + §3).
 
@@ -180,15 +224,7 @@ def draft_tree_tokens(dp, cfg: ModelConfig, base_params, tree, h, last_tok):
             hh = jnp.broadcast_to(h[:, None, :], (B, len(nodes), h.shape[-1]))
             lg = head_logits(dp, cfg, base_params, head_i, hh, path_embs)
         lp = jax.nn.log_softmax(lg, axis=-1)              # (B, n, V)
-        kmax = int(rank[nodes].max()) + 1
-        top_lp, top_tok = jax.lax.top_k(lp, kmax)         # (B, n, kmax)
-        r = jnp.asarray(rank[nodes])                      # (n,)
-        sel_tok = jnp.take_along_axis(
-            top_tok, jnp.broadcast_to(r[None, :, None], (B, len(nodes), 1)),
-            axis=2)[:, :, 0]
-        sel_lp = jnp.take_along_axis(
-            top_lp, jnp.broadcast_to(r[None, :, None], (B, len(nodes), 1)),
-            axis=2)[:, :, 0]
+        sel_lp, sel_tok = top_k_at_ranks(lp, rank[nodes])  # (B, n)
         tokens = tokens.at[:, jnp.asarray(nodes)].set(sel_tok)
         logp = logp.at[:, jnp.asarray(nodes)].set(sel_lp)
     return tokens, logp

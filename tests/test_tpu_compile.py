@@ -6,7 +6,8 @@ it serves: minitron-4b's GQA (Hq=24 over Hkv=8 heads of 128, bf16) plain
 and sliding-window, DeepSeek-V2-Lite's absorbed MLA (r=512, rd=64), and
 zamba2-7b's shared-block attention (32 heads of 224, stored as 256 lanes)
 with its Mamba2 chain kernels (``ssd_chain_verify``, ``ssd_chain_commit``)
-and its chunk path.
+and its chunk path; and the draft heads of both, which must pick their
+tree children without a sort.
 Mosaic refuses tiles and ops here that the interpreter accepts, so each
 test asserts that the program holds exactly one ``tpu_custom_call`` — the
 kernel was compiled, not interpreted, as one call — named after the jitted
@@ -242,3 +243,30 @@ def test_224_lane_page_walk_is_refused(one_chip):
         _compile_text(
             lambda *a: tree_attention_paged_bshd(*a, interpret=False), args,
             one_chip)
+
+
+@pytest.mark.parametrize("name", ["minitron-4b", "zamba2-7b"])
+def test_draft_selection_has_no_sort(one_chip, name):
+    """The served draft at the cells' shapes (16 rows; minitron-4b's
+    1/4/6/4/1 tree over 256000 words, zamba2-7b's chain of 4 over 32000)
+    picks each node's child without a sort: ``lax.top_k`` lowered to a
+    full sort of every row, two thirds of minitron-4b's verify step."""
+    from repro.configs import get_config
+    from repro.core.heads import draft_tree_tokens, init_draft_params
+    from repro.launch.specs import tree_for
+    cfg = get_config(name)
+    tree = tree_for(cfg)
+    key = jax.random.PRNGKey(0)
+    dp = jax.eval_shape(lambda: init_draft_params(key, cfg))
+    d, V, dt = cfg.d_model, cfg.vocab_size, jnp.dtype(cfg.dtype)
+    flat, treedef = jax.tree.flatten(dp)
+
+    def fn(embed, lm_head, h, last, *leaves):
+        base = {"embed": embed, "lm_head": lm_head}
+        return draft_tree_tokens(jax.tree.unflatten(treedef, leaves), cfg,
+                                 base, tree, h, last)
+
+    args = [((V, d), dt), ((d, V), dt), ((16, d), dt), ((16,), jnp.int32),
+            *[(s.shape, s.dtype) for s in flat]]
+    text = _compile_text(fn, args, one_chip)
+    assert not re.search(r"\bsort\(", text)
